@@ -50,12 +50,6 @@ def create(config=None):
 
     → org/redisson/Redisson.java#create
     """
-    try:
-        from redisson_tpu.client import RedissonTpuClient
-    except ImportError as e:  # pragma: no cover - removed once client lands
-        raise NotImplementedError(
-            "redisson_tpu.client is not built yet (L3 of the build plan); "
-            "the L0 kernel/golden layers are usable directly"
-        ) from e
+    from redisson_tpu.client import RedissonTpuClient
 
     return RedissonTpuClient(config or Config())

@@ -149,7 +149,8 @@ def main(argv=None) -> int:
     )
     p.add_argument(
         "--platform", default=None,
-        help="jax platform override (e.g. cpu for a host-only server)",
+        help="jax platform for this server's engine (e.g. cpu for a "
+        "host-only server); sets JAX_PLATFORMS before jax loads",
     )
     p.add_argument(
         "--requirepass", default=None,
@@ -267,6 +268,11 @@ def main(argv=None) -> int:
     p.add_argument("--frontdoor-dir", default=None,
                    help=argparse.SUPPRESS)
     args = p.parse_args(argv)
+    if args.platform:
+        # Before anything imports jax: the env var is read at import.
+        import os
+
+        os.environ["JAX_PLATFORMS"] = args.platform
 
     if args.federate:
         # Standalone federation mode: just the merged metrics endpoint
@@ -297,7 +303,6 @@ def main(argv=None) -> int:
 
     import redisson_tpu
     from redisson_tpu import Config
-    from redisson_tpu.serve.resp import RespServer
 
     if args.config:
         import os
@@ -307,8 +312,6 @@ def main(argv=None) -> int:
         cfg = Config.from_yaml(args.config)
     else:
         cfg = Config().use_tpu_sketch()
-    if args.platform:
-        cfg.tpu_sketch.platform = args.platform
     if args.snapshot_dir:
         cfg.snapshot_dir = args.snapshot_dir
     if args.snapshot_interval_s:
@@ -385,6 +388,12 @@ def main(argv=None) -> int:
         fd_k = multicore.effective_processes(fd_req)
         if fd_k > 1:
             return _serve_multicore(args, fd_k)
+    # Past the supervisor branch: this process serves, so it may load
+    # jax (the multicore parent never does — a chip is one process's).
+    from redisson_tpu.serve.resp import RespServer
+    from redisson_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     if args.frontdoor_index is not None:
         import os
 
@@ -401,23 +410,17 @@ def main(argv=None) -> int:
             cfg.journal_dir = os.path.join(cfg.journal_dir, sub)
             os.makedirs(cfg.journal_dir, exist_ok=True)
         # Device pinning (satellite): each worker takes a contiguous
-        # 1/K of the local devices when the node has that many; the
-        # spawn env already fixed JAX_PLATFORMS, so enumerating here is
-        # safe.
+        # 1/K of the local devices; a chip backend with fewer devices
+        # than workers refuses to start (raises, naming both counts).
         if cfg.tpu_sketch.device_indices is None:
+            import jax
+
             from redisson_tpu.serve.multicore import device_slice_for_worker
 
-            if args.platform and "JAX_PLATFORMS" not in os.environ:
-                os.environ["JAX_PLATFORMS"] = args.platform
-            try:
-                import jax
-
-                cfg.tpu_sketch.device_indices = device_slice_for_worker(
-                    args.frontdoor_index, cfg.frontdoor_workers,
-                    len(jax.devices()),
-                )
-            except Exception:
-                pass  # backend unavailable: first-come allocation
+            cfg.tpu_sketch.device_indices = device_slice_for_worker(
+                args.frontdoor_index, cfg.frontdoor_workers,
+                len(jax.devices()), jax.default_backend(),
+            )
 
     repl_master = getattr(cfg, "replica_of", None)
     if repl_master:

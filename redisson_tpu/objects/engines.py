@@ -1778,7 +1778,7 @@ class TpuSketchEngine(SketchDurabilityMixin):
         ):
             # Run-length path: row/m/is_add are constant across this call,
             # so they ride the segment as ONE meta tuple instead of B-long
-            # arrays — ~22→~8 bytes/op on the wire (PROFILE.md lever 1) and
+            # arrays — ~22→~8 bytes/op on the wire and
             # no np.full per submit on the producer thread.
             if lengths.ndim == 0:
                 len_meta = int(lengths)

@@ -42,16 +42,33 @@ def bloom_add(flat_words, rows, h1m, h2m, *, m: int, k: int, words_per_row: int,
     by all earlier keys in the batch.  ``valid``: optional bool[B] padding
     mask — invalid ops are routed to the scratch word and write nothing.
     """
-    idx = bitops.expand_km_indexes(h1m, h2m, m, k)
-    gword, bit = _op_words(rows[:, None], idx, words_per_row)
-    if valid is not None:
+    def kernel(flat, rows, h1m, h2m, valid, m):
+        idx = bitops.expand_km_indexes(h1m, h2m, m, k)
+        gword, bit = _op_words(rows[:, None], idx, words_per_row)
         gword = bitops.route_invalid_to_scratch(
-            gword, valid[:, None], flat_words.shape[0]
-        )
-    gw, bt = gword.reshape(-1), bit.reshape(-1)
-    new, prev = bitops.scatter_set_bits(flat_words, gw, bt)
-    newly = (prev == 0).reshape(idx.shape).any(axis=1)
-    return new, newly
+            gword, valid[:, None], flat.shape[0])
+        new, prev = bitops.scatter_set_bits(
+            flat, gword.reshape(-1), bit.reshape(-1))
+        return new, (prev == 0).reshape(idx.shape).any(axis=1)
+
+    return _scan_ops(kernel, flat_words, k, m, valid, rows, h1m, h2m)
+
+
+def _scan_ops(kernel, flat_words, k: int, m, valid, *cols):
+    """bitops.scan_chunks over OPS (each expands to k sorted elements):
+    ``kernel(flat, *cols, valid, m)`` sees one chunk of at most
+    SORT_CHUNK // k ops.  Padding ops are invalid (scratch word, m=1)."""
+    B = cols[0].shape[0]
+    if valid is None:
+        valid = jnp.ones((B,), bool)
+    chunk = 1 << max(0, (bitops.SORT_CHUNK // k).bit_length() - 1)
+    if isinstance(m, (int, np.integer)):  # static m stays static
+        return bitops.scan_chunks(
+            lambda f, *c: kernel(f, *c, m), flat_words,
+            cols + (valid,), (0,) * len(cols) + (False,), chunk)
+    return bitops.scan_chunks(
+        kernel, flat_words, cols + (valid, m),
+        (0,) * len(cols) + (False, 1), chunk)
 
 
 def bloom_mixed(flat_words, rows, h1m, h2m, is_add, *, m, k: int, words_per_row: int, valid=None):
@@ -67,18 +84,18 @@ def bloom_mixed(flat_words, rows, h1m, h2m, is_add, *, m, k: int, words_per_row:
     breaking a new segment on every add/contains alternation.
     Returns (new_flat, result bool[B]).
     """
-    idx = bitops.expand_km_indexes(h1m, h2m, m, k)
-    gword, bit = _op_words(rows[:, None], idx, words_per_row)
-    if valid is not None:
+    def kernel(flat, rows, h1m, h2m, is_add, valid, m):
+        idx = bitops.expand_km_indexes(h1m, h2m, m, k)
+        gword, bit = _op_words(rows[:, None], idx, words_per_row)
         gword = bitops.route_invalid_to_scratch(
-            gword, valid[:, None], flat_words.shape[0]
-        )
-    gw, bt = gword.reshape(-1), bit.reshape(-1)
-    wr = jnp.broadcast_to(is_add[:, None], idx.shape).reshape(-1)
-    new, obs = bitops.scatter_set_bits_masked(flat_words, gw, bt, wr)
-    all_set = (obs == 1).reshape(idx.shape).all(axis=1)
-    result = jnp.where(is_add, ~all_set, all_set)
-    return new, result
+            gword, valid[:, None], flat.shape[0])
+        wr = jnp.broadcast_to(is_add[:, None], idx.shape).reshape(-1)
+        new, obs = bitops.scatter_set_bits_masked(
+            flat, gword.reshape(-1), bit.reshape(-1), wr)
+        all_set = (obs == 1).reshape(idx.shape).all(axis=1)
+        return new, jnp.where(is_add, ~all_set, all_set)
+
+    return _scan_ops(kernel, flat_words, k, m, valid, rows, h1m, h2m, is_add)
 
 
 def bloom_cardinality(flat_words, row, *, m: int, k: int, words_per_row: int):

@@ -93,17 +93,24 @@ def hll_add_changed(flat_regs, rows, c0, c1, c2, valid=None):
     rank_j > max(pre-batch value, ranks of earlier ops on the same
     register).  Sort by register + segmented exclusive max scan (the
     coalesced-path variant of RHyperLogLog#add's boolean)."""
-    from jax import lax
-
     idx, rank = hll_index_rank_device(c0, c1, c2)
     if valid is not None:
         rank = jnp.where(valid, rank, np.uint8(0))
     gidx = (rows * np.int32(HLL_M) + idx).astype(jnp.uint32)
-    new = bitops.scatter_max_onehot(flat_regs, gidx.astype(jnp.int32), rank)
+    # Chunked like the bit kernels (bitops.SORT_CHUNK); rank 0 pads.
+    return bitops.scan_chunks(
+        _add_changed_chunk, flat_regs, (gidx, rank.astype(jnp.int32)),
+        (flat_regs.shape[0] - 1, 0),
+    )
 
+
+def _add_changed_chunk(flat_regs, gidx, rank):
+    from jax import lax
+
+    new = bitops.scatter_max_onehot(flat_regs, gidx.astype(jnp.int32), rank)
     n = gidx.shape[0]
     pos = jnp.arange(n, dtype=jnp.int32)
-    sg, sr, sp = lax.sort((gidx, rank.astype(jnp.int32), pos), num_keys=1, is_stable=True)
+    sg, sr, sp = lax.sort((gidx, rank, pos), num_keys=1, is_stable=True)
     pre = bitops.gather_words(flat_regs, sg).astype(jnp.int32)
     first = jnp.concatenate([jnp.ones((1,), bool), sg[1:] != sg[:-1]])
     run_prev = bitops.segmented_exclusive_max(first, sr)

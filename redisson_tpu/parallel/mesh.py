@@ -33,14 +33,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from redisson_tpu.ops import bitops, bloom, hll as hll_ops
 
-# jax.shard_map graduated from jax.experimental in newer releases; the
-# keyword call shape (f, mesh=, in_specs=, out_specs=) is identical in
-# both homes, so bind whichever this jax provides.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pre-graduation jax
-    from jax.experimental.shard_map import shard_map
-
 
 class MeshContext:
     """Owns the device mesh and sharding specs (the ConnectionManager-role
@@ -97,7 +89,7 @@ def sharded_bloom_add(ctx: MeshContext, *, k: int, words_per_row: int, pack_resu
             out = bitops.pack_bool_u32(out)
         return new_local[None], out
 
-    fn = shard_map(
+    fn = jax.shard_map(
         inner,
         mesh=ctx.mesh,
         in_specs=(P("shard"), P(), P(), P(), P(), P()),
@@ -122,7 +114,7 @@ def sharded_bloom_contains(ctx: MeshContext, *, k: int, words_per_row: int, pack
             out = bitops.pack_bool_u32(out)
         return out
 
-    fn = shard_map(
+    fn = jax.shard_map(
         inner,
         mesh=ctx.mesh,
         in_specs=(P("shard"), P(), P(), P(), P(), P()),
@@ -146,7 +138,7 @@ def sharded_hll_add(ctx: MeshContext):
         new_local = hll_ops.hll_add(local, safe_rows, c0, c1, c2, valid=own)
         return new_local[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         inner,
         mesh=ctx.mesh,
         in_specs=(P("shard"), P(), P(), P(), P(), P()),
@@ -167,7 +159,7 @@ def sharded_hll_histogram(ctx: MeshContext):
         hist = lax.psum(jnp.where(own, hist, 0), "shard")
         return hist
 
-    fn = shard_map(
+    fn = jax.shard_map(
         inner, mesh=ctx.mesh, in_specs=(P("shard"), P()), out_specs=P()
     )
     return jax.jit(fn)
@@ -202,7 +194,7 @@ def sharded_mbit_set(ctx: MeshContext, *, words_local: int):
         prev = lax.psum(jnp.where(own, prev, 0).astype(jnp.int32), "shard")
         return new_local[None], prev > 0
 
-    fn = shard_map(
+    fn = jax.shard_map(
         inner,
         mesh=ctx.mesh,
         in_specs=(P("shard"), P(), P()),
@@ -227,7 +219,7 @@ def sharded_mbit_get(ctx: MeshContext, *, words_local: int):
         res = lax.psum(jnp.where(own, res, 0).astype(jnp.int32), "shard")
         return res > 0
 
-    fn = shard_map(
+    fn = jax.shard_map(
         inner, mesh=ctx.mesh, in_specs=(P("shard"), P()), out_specs=P()
     )
     return jax.jit(fn)
@@ -256,7 +248,7 @@ def _psharded(ctx: MeshContext, inner, n_op_args: int, *, out_state: bool, donat
         return inner(local, *cols)
 
     out_specs = (P("shard"), P("shard")) if out_state else P("shard")
-    fn = shard_map(
+    fn = jax.shard_map(
         wrapped,
         mesh=ctx.mesh,
         in_specs=(P("shard"),) + (P("shard"),) * n_op_args,
@@ -384,7 +376,7 @@ def psharded_cms_update_estimate(ctx: MeshContext, *, d: int, w: int, cells_per_
     if update_only:
         def wrapped(state, *ops):
             return inner(state[0], *[o[0] for o in ops])
-        fn = shard_map(
+        fn = jax.shard_map(
             wrapped,
             mesh=ctx.mesh,
             in_specs=(P("shard"),) * 6,
@@ -415,7 +407,7 @@ def msharded_row_map(ctx: MeshContext, fn_local):
         v = jnp.asarray(fn_local(state[0], row))
         return v[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         inner, mesh=ctx.mesh, in_specs=(P("shard"), P()), out_specs=P("shard")
     )
     return jax.jit(fn)
@@ -428,7 +420,7 @@ def msharded_row_write(ctx: MeshContext, *, words_local: int):
         local = state[0]
         return bitops.row_update(local, row, data[0], words_local)[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         inner,
         mesh=ctx.mesh,
         in_specs=(P("shard"), P(), P("shard")),
@@ -448,7 +440,7 @@ def msharded_set_range(ctx: MeshContext, *, words_local: int, value: bool):
         new_row = (cur | mask) if value else (cur & ~mask)
         return bitops.row_update(local, row, new_row, words_local)[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         inner,
         mesh=ctx.mesh,
         in_specs=(P("shard"), P(), P("shard"), P("shard")),
@@ -472,7 +464,7 @@ def msharded_bitop(ctx: MeshContext, *, words_local: int, op: str, n_src: int, m
             n_src=n_src, limit_bits=limit[0] if masked else None,
         )[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         inner,
         mesh=ctx.mesh,
         in_specs=(P("shard"), P(), P(), P("shard")),
@@ -513,7 +505,7 @@ def sharded_hll_merge(ctx: MeshContext):
         new_local = bitops.row_update(local, dst_local, new_row, HLL_M)
         return new_local[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         inner, mesh=ctx.mesh, in_specs=(P("shard"), P(), P()), out_specs=P("shard")
     )
     return jax.jit(fn, donate_argnums=(0,))
@@ -561,7 +553,7 @@ def sharded_bitop(ctx: MeshContext, *, words_per_row: int, op: str, n_src: int, 
         new_local = bitops.row_update(local, dst_local, new_row, words_per_row)
         return new_local[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         inner,
         mesh=ctx.mesh,
         in_specs=(P("shard"), P(), P(), P()),
@@ -591,7 +583,7 @@ def sharded_bitset_set_range(ctx: MeshContext, *, words_per_row: int, value: boo
         new_row = jnp.where(own, new_row, cur)
         return bitops.row_update(local, lrow, new_row, words_per_row)[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         inner,
         mesh=ctx.mesh,
         in_specs=(P("shard"), P(), P(), P()),
@@ -614,7 +606,7 @@ def sharded_row_reduce(ctx: MeshContext, fn_local):
         v = fn_local(local, row // S)
         return lax.psum(jnp.where(own, v, 0), "shard")
 
-    fn = shard_map(
+    fn = jax.shard_map(
         inner, mesh=ctx.mesh, in_specs=(P("shard"), P()), out_specs=P()
     )
     return jax.jit(fn)
@@ -634,7 +626,7 @@ def sharded_row_read(ctx: MeshContext, *, row_units: int):
         # exact broadcast (no overflow possible).
         return lax.psum(jnp.where(own, v, jnp.zeros_like(v)), "shard")
 
-    fn = shard_map(
+    fn = jax.shard_map(
         inner, mesh=ctx.mesh, in_specs=(P("shard"), P()), out_specs=P()
     )
     return jax.jit(fn)
@@ -653,7 +645,7 @@ def sharded_row_write(ctx: MeshContext, *, row_units: int):
         new_row = jnp.where(own, data, cur)
         return bitops.row_update(local, lrow, new_row, row_units)[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         inner,
         mesh=ctx.mesh,
         in_specs=(P("shard"), P(), P()),
@@ -683,7 +675,7 @@ def sharded_cms_merge(ctx: MeshContext, *, cells_per_row: int):
         new_row = jnp.where(own_dst, cur + summed, cur)
         return bitops.row_update(local, dst_local, new_row, cells_per_row)[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         inner, mesh=ctx.mesh, in_specs=(P("shard"), P(), P()), out_specs=P("shard")
     )
     return jax.jit(fn, donate_argnums=(0,))

@@ -106,14 +106,21 @@ def effective_processes(requested) -> int:
     return k
 
 
-def device_slice_for_worker(index: int, nworkers: int,
-                            ndevices: int) -> Optional[list]:
+def device_slice_for_worker(index: int, nworkers: int, ndevices: int,
+                            backend: str = "cpu") -> Optional[list]:
     """Contiguous per-worker device-index slice (the device analog of
-    the slot partition).  None when the node has fewer devices than
-    workers — then every worker shares the default enumeration (the
-    CPU-backend test shape)."""
+    the slot partition).  With fewer devices than workers the CPU
+    backend returns None — every worker shares the default enumeration
+    (the test shape) — and a chip backend raises: a chip belongs to one
+    process, so K workers cannot share it."""
     if ndevices < nworkers:
-        return None
+        if backend == "cpu":
+            return None
+        raise RuntimeError(
+            f"front door wants {nworkers} worker processes but the "
+            f"{backend} backend has {ndevices} device(s); a chip belongs "
+            f"to one process — serve with at most {ndevices} worker(s)"
+        )
     lo = index * ndevices // nworkers
     hi = (index + 1) * ndevices // nworkers
     return list(range(lo, hi))
@@ -585,10 +592,13 @@ class MulticoreNode:
             path = peer_sock_path(self.rundir, i)
             while True:
                 if self.procs[i].poll() is not None:
+                    log_path = os.path.join(self.rundir, f"worker{i}.log")
+                    with open(log_path, "rb") as f:
+                        tail = f.read()[-600:].decode("utf-8", "replace")
                     raise RuntimeError(
                         f"front-door worker {i} exited rc="
-                        f"{self.procs[i].returncode} during startup; see "
-                        f"{self.rundir}/worker{i}.log"
+                        f"{self.procs[i].returncode} during startup; "
+                        f"{log_path} ends:\n{tail}"
                     )
                 try:
                     s = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)

@@ -1,4 +1,4 @@
-"""Device-side result mailbox (PROFILE.md remaining-lever 2): a group of
+"""Device-side result mailbox: a group of
 launches' packed results concatenates on device and fetches in ONE D2H.
 Parity discipline: mailbox-collected results must be bit-identical to
 per-launch fetches through every path (direct, bulk API, coalescer)."""

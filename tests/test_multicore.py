@@ -14,6 +14,8 @@ CI multicore-smoke job runs.
 import logging
 import os
 import socket
+import subprocess
+import sys
 import time
 
 import numpy as np
@@ -107,6 +109,25 @@ def test_device_slice_for_worker_partitions_devices():
     # Uneven split still covers every device exactly once.
     got = [d for i in range(3) for d in device_slice_for_worker(i, 3, 8)]
     assert got == list(range(8))
+
+
+def test_device_slice_for_worker_refuses_shared_chip():
+    # A chip backend with fewer devices than workers refuses to start —
+    # K processes cannot each open the one chip.
+    with pytest.raises(RuntimeError, match=r"4 worker processes.*1 device"):
+        device_slice_for_worker(0, 4, 1, "tpu")
+    assert device_slice_for_worker(1, 4, 4, "tpu") == [1]
+
+
+def test_supervisor_path_never_imports_jax():
+    # The multicore parent only spawns the workers that own the devices;
+    # loading jax there could take the chip they need.
+    code = ("import sys, redisson_tpu.__main__, redisson_tpu.serve.multicore,"
+            " redisson_tpu.obs.federate; print('jax' in sys.modules)")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=60, check=True, cwd=root)
+    assert out.stdout.strip() == "False"
 
 
 # -- device-slice pinning (ISSUE 17 satellite, ROADMAP carry-over) ------------

@@ -1,4 +1,4 @@
-"""Run-length segment metadata path (round 4, PROFILE.md lever 1):
+"""Run-length segment metadata path (round 4):
 per-chunk row/m/is_add ship once per run and expand on device.  These
 tests pin equivalence with the per-op-array path and the golden engine."""
 
