@@ -46,12 +46,12 @@ from redisson_tpu.executor.tpu_executor import defer_host_fetch
 from concurrent.futures import Future
 from typing import Callable, Optional
 
-import jax  # already a transitive import (tpu_executor): free here
 import numpy as np
 
 from redisson_tpu import chaos as _chaos
 from redisson_tpu.analysis import witness as _witness
 from redisson_tpu.obs import trace as _trace
+from redisson_tpu.obs.spans import region as _region
 from redisson_tpu.executor.failures import (
     DeadlineExceededError,
     DispatchTimeoutError,
@@ -390,29 +390,35 @@ class BatchCoalescer:
             if _full() and not self._closed:
                 ticket = object()
                 self._admit_q.append(ticket)
+                t_admit = time.monotonic()
                 try:
-                    while not self._closed and (
-                        self._admit_q[0] is not ticket or _full()
-                    ):
-                        wait_s = 1.0
-                        if deadline is not None:
-                            wait_s = deadline - time.monotonic()
-                            if wait_s <= 0:
-                                self._count_shed("deadline", "queue", nops)
-                                raise DeadlineExceededError(
-                                    f"queue full past op deadline "
-                                    f"({_op_label(key)}, {nops} ops)",
-                                    stage="queue",
-                                )
-                            wait_s = min(wait_s, 1.0)
-                        self._wake.notify()
-                        self._admit.wait(timeout=wait_s)
+                    with _region("rtpu:admit_wait"):
+                        while not self._closed and (
+                            self._admit_q[0] is not ticket or _full()
+                        ):
+                            wait_s = 1.0
+                            if deadline is not None:
+                                wait_s = deadline - time.monotonic()
+                                if wait_s <= 0:
+                                    self._count_shed("deadline", "queue", nops)
+                                    raise DeadlineExceededError(
+                                        f"queue full past op deadline "
+                                        f"({_op_label(key)}, {nops} ops)",
+                                        stage="queue",
+                                    )
+                                wait_s = min(wait_s, 1.0)
+                            self._wake.notify()
+                            self._admit.wait(timeout=wait_s)
                 finally:
                     try:
                         self._admit_q.remove(ticket)
                     except ValueError:  # pragma: no cover
                         pass
                     self._admit.notify_all()  # next ticket re-checks
+                    if self.obs is not None:
+                        self.obs.admit_wait_seconds.observe(
+                            (), time.monotonic() - t_admit
+                        )
             if self._closed:
                 raise RuntimeError("coalescer is shut down")
             seg = self._open.get(key)
@@ -738,11 +744,15 @@ class BatchCoalescer:
                     # Everything queued is parked (backoff) or young:
                     # wait until the earliest deadline or a notify from a
                     # full batch / a blocking caller's hint.
-                    timeout = (
-                        0.05 if deadline is None
-                        else min(max(deadline - now, 0.0005), 0.05)
-                    )
-                    self._wake.wait(timeout=timeout)
+                    if deadline is None:
+                        self._wake.wait(timeout=0.05)
+                        continue
+                    # A young segment's window (or a parked backoff)
+                    # holds the next launch back.
+                    with _region("rtpu:coalesce:window"):
+                        self._wake.wait(
+                            timeout=min(max(deadline - now, 0.0005), 0.05)
+                        )
                     continue
                 self._pop_seg_locked(seg)
                 if seg.dispatch is not None and not self._all_expired(
@@ -771,11 +781,12 @@ class BatchCoalescer:
                 # dispatch, keeping the transport's in-flight window
                 # shallow and letting the queue behind us keep merging.
                 try:
-                    cols = self._stage(seg)
+                    with _region("rtpu:coalesce:stage"):
+                        cols = self._stage(seg)
                 except Exception as e:
                     stage_exc = e
                 if stage_exc is None:
-                    self._acquire_launch_slot()
+                    self._acquire_launch_slot(_op_label(seg.key))
             self._flush(seg, cols, stage_exc)
 
     def _stage(self, seg: _Segment) -> list:
@@ -793,11 +804,20 @@ class BatchCoalescer:
             seg.span.stamp("host_stage")
         return cols
 
-    def _acquire_launch_slot(self) -> None:
-        with self._inflight_cv:
-            while self._uncollected >= self._inflight_limit:
-                self._inflight_cv.wait(timeout=0.5)
-            self._uncollected += 1
+    def _acquire_launch_slot(self, op: str) -> None:
+        """Wait for an in-flight slot; the wait is recorded per launch in
+        ``rtpu_launch_slot_wait_seconds{op}`` (it stays inside the span's
+        device_dispatch phase)."""
+        t0 = time.monotonic()
+        with _region("rtpu:coalesce:slot_wait"):
+            with self._inflight_cv:
+                while self._uncollected >= self._inflight_limit:
+                    self._inflight_cv.wait(timeout=0.5)
+                self._uncollected += 1
+        if self.obs is not None:
+            self.obs.launch_slot_wait_seconds.observe(
+                (op,), time.monotonic() - t0
+            )
 
     def _release_launch_slot(self, collect_s: Optional[float],
                              genuine: bool = True) -> None:
@@ -891,17 +911,6 @@ class BatchCoalescer:
                 )
                 else contextlib.nullcontext()
             )
-            if self.obs is not None:
-                # Correlates the host span's device-dispatch phase with
-                # the device trace: the annotation names the region in a
-                # jax.profiler capture (docs/observability.md).  A fresh
-                # annotation per attempt — the name is built once.
-                ann_name = "rtpu:dispatch:" + _op_label(seg.key)
-
-                def _ann():
-                    return jax.profiler.TraceAnnotation(ann_name)
-            else:
-                _ann = contextlib.nullcontext
             op = _op_label(seg.key)
             h = self._health
             if h is not None and not h.allow_dispatch(op):
@@ -916,7 +925,10 @@ class BatchCoalescer:
                 )
             lazy = None
             try:
-                with fetch_ctx, _ann():
+                # Correlates the host span's device-dispatch phase with
+                # the device trace: the host region rtpu:dispatch:<op> in
+                # a jax.profiler capture (docs/observability.md).
+                with fetch_ctx, _region("rtpu:dispatch:" + op):
                     if seg.metas is not None:
                         lazy = seg.dispatch(cols, seg.metas)
                     else:
@@ -999,15 +1011,17 @@ class BatchCoalescer:
             t_collect = time.monotonic()
             if len(group) > 1:
                 try:
-                    self._group_collect(
-                        [lazy for _, lazy, _ in group if lazy is not None]
-                    )
+                    with _region("rtpu:complete:fetch"):
+                        self._group_collect(
+                            [lazy for _, lazy, _ in group if lazy is not None]
+                        )
                 except Exception:
                     pass  # per-item .result() below surfaces the failure
             first = True
             for seg, lazy, t0 in group:
                 try:
-                    res = lazy.result() if lazy is not None else None
+                    with _region("rtpu:complete:fetch"):
+                        res = lazy.result() if lazy is not None else None
                     self._release_launch_slot(
                         time.monotonic() - t_collect if first else None,
                         genuine=genuine,
@@ -1050,11 +1064,13 @@ class BatchCoalescer:
                         for _, _, n, tenant, _dl in seg.futures:
                             if tenant is not None:
                                 self.obs.tenant_ops.inc((tenant, op), n)
-                    for fut, start, n, _, _dl in seg.futures:
-                        if fut.set_running_or_notify_cancel():
-                            fut.set_result(
-                                None if res is None else res[start : start + n]
-                            )
+                    with _region("rtpu:complete:resolve"):
+                        for fut, start, n, _, _dl in seg.futures:
+                            if fut.set_running_or_notify_cancel():
+                                fut.set_result(
+                                    None if res is None
+                                    else res[start : start + n]
+                                )
                 except Exception as e:
                     # Completion-time failure: the device batch died after
                     # donation — NOT retryable; attribute each caller's op

@@ -36,6 +36,7 @@ from redisson_tpu.ops import cms as cms_ops
 from redisson_tpu.ops import fastpath
 from redisson_tpu.ops import golden
 from redisson_tpu.ops import hll as hll_ops
+from redisson_tpu.obs import spans as _spans
 from redisson_tpu.tenancy import SizeClassPool
 
 
@@ -91,8 +92,11 @@ def ensure_addressable(arr):
     if rep is None:
         from jax.sharding import NamedSharding, PartitionSpec
 
+        def replicate(a):
+            return a
+
         rep = jax.jit(
-            lambda a: a, out_shardings=NamedSharding(mesh, PartitionSpec())
+            replicate, out_shardings=NamedSharding(mesh, PartitionSpec())
         )
         _REPLICATORS[mesh] = rep
     return rep(arr)
@@ -207,6 +211,8 @@ def _put_staged(slot: "_StagingSlot", view):
     accelerators; via a private copy on the zero-copy CPU backend."""
     if _chaos.ENABLED:  # staged-H2D fault point (ISSUE 3)
         _chaos.fire("h2d.staging", data=view)
+    # rtpu_h2d_bytes: the dispatching method records the sum once.
+    _spans.DISPATCH.h2d_bytes += view.nbytes
     if _host_may_alias():
         return jax.device_put(view.copy())
     dev = jax.device_put(view)
@@ -354,6 +360,7 @@ class TpuCommandExecutor:
         # comment): the hot coalesced methods pack whole batches into one
         # block here; everything else pads into reusable column buffers.
         self._staging = _StagingRings()
+        _spans.install_compile_listener()  # rtpu_compiles{method}
         # Explicit device pinning (ISSUE 17 satellite): when the config
         # names a device slice, every allocation this executor makes —
         # pool-state factory jnp.zeros, staging device_puts — lands on
@@ -433,7 +440,11 @@ class TpuCommandExecutor:
             with self._lock:
                 fn = self._jit_cache.get(key)
                 if fn is None:
-                    fn = jax.jit(build(), donate_argnums=(0,) if donate else ())
+                    f = build()
+                    # The XLA module, and so every device op in a trace,
+                    # is named jit_<op> after the key's op name.
+                    f.__name__ = f.__qualname__ = key[0]
+                    fn = jax.jit(f, donate_argnums=(0,) if donate else ())
                     self._jit_cache[key] = fn
         return fn
 
@@ -457,6 +468,15 @@ class TpuCommandExecutor:
         members resolve via the single fetch below, and singleton-sig
         stragglers get their async copy kicked off in the loop so they
         overlap instead of serializing one round trip each."""
+        d = _spans.DISPATCH  # label the mailbox concat's compiles
+        prev = d.method, d.obs
+        d.method, d.obs = "collect_group", self.obs
+        try:
+            return self._collect_group(lazies)
+        finally:
+            d.method, d.obs = prev
+
+    def _collect_group(self, lazies) -> None:
         by_sig: dict = {}
         for l in lazies:
             # Unwrap MappedFuture-style adapters (objects/base.py): the
@@ -1618,7 +1638,7 @@ def _locked(fn):
     )
 
     name = fn.__name__
-    annotation = "rtpu:" + name  # device-trace label (one str, not per call)
+    annotation = "rtpu:" + name  # host-region label (one str, not per call)
     # Chaos fault point, one interned string per method (zero per-call
     # allocation): rules can target one method ("dispatch.bloom_mixed")
     # or the whole boundary ("dispatch").
@@ -1692,14 +1712,18 @@ def _locked(fn):
                 # the reentrant dispatch lock on this thread.
                 return fn(self, *args, **kwargs)
             self._dispatch_recording = True
+            # Compiles firing inside are labelled with this method
+            # (rtpu_compiles); the staging helpers add the H2D bytes.
+            d = _spans.DISPATCH
+            d.method, d.obs, d.h2d_bytes = name, obs, 0
             t0 = time.monotonic()
             try:
-                # Named region in a jax.profiler capture: device trace
-                # rows correlate with host spans/histograms by op name.
-                with jax.profiler.TraceAnnotation(annotation):
+                # Host region in a jax.profiler capture, named by method.
+                with _spans.region(annotation):
                     out = fn(self, *args, **kwargs)
             finally:
                 self._dispatch_recording = False
+                d.method = d.obs = None
             dur = time.monotonic() - t0
             nops = _nops_of(name, args)
             if metrics is not None:
@@ -1708,7 +1732,7 @@ def _locked(fn):
                 # longer report zero ops (ISSUE 1 satellite).
                 metrics.record_dispatch(nops=nops, enqueue_s=dur)
             if obs is not None:
-                obs.record_dispatch(name, nops, dur)
+                obs.record_dispatch(name, nops, dur, d.h2d_bytes)
             return out
 
     return wrapper

@@ -147,9 +147,13 @@ class RObject(CamelCompatMixin):
     # -- hashing helpers shared by sketch objects --------------------------
 
     def _encode(self, objs) -> tuple[np.ndarray, np.ndarray]:
+        """Key encoding, the one site the API and the RESP door share:
+        timed into ``rtpu_encode_seconds{kind}`` and named ``rtpu:encode``
+        in a capture, once per call."""
         if np.isscalar(objs) or isinstance(objs, (str, bytes)):
             objs = [objs]
-        return encode_batch(self._codec, objs)
+        with self._engine.obs.encode_timer(self.KIND or "object"):
+            return encode_batch(self._codec, objs)
 
     def _hash_lanes(self, objs):
         blocks, lengths = self._encode(objs)

@@ -29,6 +29,7 @@ from redisson_tpu import overload as _ovl
 from redisson_tpu.analysis import witness as _witness
 from redisson_tpu.executor import LazyResult, TpuCommandExecutor
 from redisson_tpu.objects.durability import SketchDurabilityMixin
+from redisson_tpu.obs.spans import WaitRegionLock
 from redisson_tpu.ops import golden
 from redisson_tpu.tenancy import PoolKind, TenantRegistry
 from redisson_tpu.tenancy.registry import class_words_for_bits
@@ -525,7 +526,9 @@ class TpuSketchEngine(SketchDurabilityMixin):
         )
         self.journal = None
         self._journal_replaying = False
-        self._journal_gate = threading.RLock()
+        # Contended acquisitions are the host region rtpu:lock_wait.
+        self._journal_gate = WaitRegionLock(threading.RLock(),
+                                            "rtpu:lock_wait")
         # Snapshot serialization: SAVE, BGSAVE's thread, the periodic
         # snapshotter, BGREWRITEAOF and shutdown may all call snapshot()
         # concurrently — without one writer at a time, an OLDER capture
@@ -1849,7 +1852,24 @@ class TpuSketchEngine(SketchDurabilityMixin):
             self._replication_fence(entry, saw_replicas, redo)
         return fut if gather is None else _MappedFuture(fut, gather)
 
+    # The three encoded Bloom entries are the serving path's submits:
+    # each is timed into rtpu_submit_seconds{op} and named
+    # rtpu:submit:<op> in a capture, once per call (internal delegation
+    # goes to the untimed bodies).
+
     def bloom_add_encoded(self, name, blocks, lengths) -> LazyResult:
+        with self.obs.submit_timer("bloom_add"):
+            return self._bloom_add_encoded(name, blocks, lengths)
+
+    def bloom_contains_encoded(self, name, blocks, lengths) -> LazyResult:
+        with self.obs.submit_timer("bloom_contains"):
+            return self._bloom_contains_encoded(name, blocks, lengths)
+
+    def bloom_mixed_encoded(self, name, blocks, lengths, flags) -> LazyResult:
+        with self.obs.submit_timer("bloom_mixed"):
+            return self._bloom_mixed_encoded(name, blocks, lengths, flags)
+
+    def _bloom_add_encoded(self, name, blocks, lengths) -> LazyResult:
         if self.executor.supports_device_hash:
             with self._nc_mutate(name), self._journal_gate:
                 entry = self._require(name, PoolKind.BLOOM)
@@ -1902,7 +1922,7 @@ class TpuSketchEngine(SketchDurabilityMixin):
         except Exception:
             pass
 
-    def bloom_contains_encoded(self, name, blocks, lengths) -> LazyResult:
+    def _bloom_contains_encoded(self, name, blocks, lengths) -> LazyResult:
         if not self.executor.supports_device_hash:
             return self.bloom_contains(name, *hashing.hash128_np(blocks, lengths))
         nc = self.nearcache
@@ -1943,7 +1963,7 @@ class TpuSketchEngine(SketchDurabilityMixin):
             entry.pool, self._tier_row(entry, row0), m, k, blocks, lengths
         )
 
-    def bloom_mixed_encoded(self, name, blocks, lengths, flags) -> LazyResult:
+    def _bloom_mixed_encoded(self, name, blocks, lengths, flags) -> LazyResult:
         """Front-door fused run (ISSUE 6): one ordered add/contains mix on
         one filter as ONE engine call — per-op results (newly-added for
         add ops, membership for contains ops) come back in command order.
@@ -1952,9 +1972,9 @@ class TpuSketchEngine(SketchDurabilityMixin):
         of 500 pipelined BF.ADD/BF.EXISTS costs one launch, not 500."""
         flags = np.asarray(flags, bool)
         if not flags.any():
-            return self.bloom_contains_encoded(name, blocks, lengths)
+            return self._bloom_contains_encoded(name, blocks, lengths)
         if flags.all():
-            return self.bloom_add_encoded(name, blocks, lengths)
+            return self._bloom_add_encoded(name, blocks, lengths)
         with self._nc_mutate(name), self._journal_gate:
             entry = self._require(name, PoolKind.BLOOM)
             # Journal the ADD subset only (contains ops have no state

@@ -20,7 +20,7 @@ from redisson_tpu.obs.latency import LatencyMonitor
 from redisson_tpu.obs.loadmap import LoadMap
 from redisson_tpu.obs.registry import Family, MetricsRegistry
 from redisson_tpu.obs.slowlog import SlowLog, SlowLogEntry
-from redisson_tpu.obs.spans import OpSpan, SpanRecorder
+from redisson_tpu.obs.spans import OpSpan, SpanRecorder, Timed, register_bundle
 from redisson_tpu.obs.trace import Tracer
 
 
@@ -83,6 +83,47 @@ class Observability:
             "host-side dispatch enqueue time, by method", ("method",))
         self.shard_ops = r.counter(
             "rtpu_shard_ops", "ops routed to each mesh shard", ("shard",))
+        # Launch-path timing (docs/observability.md "Host regions"): each
+        # family pairs with a constant-named rtpu:* region of the same
+        # work, one observation per call, launch or collection.
+        self.encode_seconds = r.histogram(
+            "rtpu_encode_seconds",
+            "client-side key encoding time per call, by object kind",
+            ("kind",))
+        self.submit_seconds = r.histogram(
+            "rtpu_submit_seconds",
+            "engine submit time per call, engine entry to returned "
+            "future (encode excluded), by op", ("op",))
+        self.admit_wait_seconds = r.histogram(
+            "rtpu_admit_wait_seconds",
+            "time a submit waited on the coalescer's queue bound (one "
+            "observation per submit that waited)")
+        self.launch_slot_wait_seconds = r.histogram(
+            "rtpu_launch_slot_wait_seconds",
+            "flush-thread wait for an in-flight launch slot, per launch "
+            "(part of the device_dispatch phase), by op", ("op",))
+        self.h2d_bytes = r.counter(
+            "rtpu_h2d_bytes",
+            "bytes shipped host-to-device by the executor's staging "
+            "helpers, by executor method", ("method",))
+        self.compiles = r.counter(
+            "rtpu_compiles",
+            "XLA backend compiles, by the executor method dispatching "
+            "when each fired (other = outside any dispatch)", ("method",))
+        self.compile_seconds = r.counter(
+            "rtpu_compile_seconds", "seconds spent in XLA backend compiles")
+        self.gc_pause_seconds = r.counter(
+            "rtpu_gc_pause_seconds",
+            "seconds the process spent in Python's cyclic collector")
+        self.gc_collections = r.counter(
+            "rtpu_gc_collections", "cyclic-collector runs, by generation",
+            ("generation",))
+        # Children the GC hook touches exist up front: the hook then
+        # never takes a family lock from inside a collection.
+        self.gc_pause_seconds.child(())
+        for g in ("0", "1", "2"):
+            self.gc_collections.child((g,))
+        register_bundle(self)
         # Robustness (ISSUE 3): degraded-mode serving + chaos injection.
         # Breaker state itself is a render-time gauge (rtpu_breaker_state,
         # registered by the engine's health-gauge wiring).
@@ -394,10 +435,21 @@ class Observability:
             self.resp_errors.inc((cmd,))
         self.resp_latency.observe((cmd,), duration_s)
 
-    def record_dispatch(self, method: str, nops: int, dur_s: float) -> None:
+    def record_dispatch(self, method: str, nops: int, dur_s: float,
+                        h2d_bytes: int = 0) -> None:
         self.dispatches.inc((method,))
         self.dispatch_ops.inc((method,), nops)
         self.dispatch_seconds.observe((method,), dur_s)
+        if h2d_bytes:
+            self.h2d_bytes.inc((method,), h2d_bytes)
+
+    def encode_timer(self, kind: str) -> Timed:
+        """``rtpu_encode_seconds{kind}`` + region ``rtpu:encode``."""
+        return Timed(self.encode_seconds.child((kind,)), "rtpu:encode")
+
+    def submit_timer(self, op: str) -> Timed:
+        """``rtpu_submit_seconds{op}`` + region ``rtpu:submit:<op>``."""
+        return Timed(self.submit_seconds.child((op,)), "rtpu:submit:" + op)
 
     def record_shard_counts(self, counts) -> None:
         for s, c in enumerate(counts):

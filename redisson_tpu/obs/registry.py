@@ -50,6 +50,17 @@ def bucket_upper_bound_us(idx: int) -> float:
     return float("inf") if idx >= N_TIME_BUCKETS else float(1 << idx)
 
 
+def observe_child(c: "_Child", seconds: float) -> None:
+    """One histogram observation into child ``c`` (bucket_index_us,
+    inlined: this is the per-call cost of every timed region)."""
+    n = math.ceil(seconds * 1e6)
+    idx = 0 if n <= 1 else min((n - 1).bit_length(), N_TIME_BUCKETS)
+    with c.lock:
+        c.buckets[idx] += 1
+        c.sum += seconds
+        c.count += 1
+
+
 class _Child:
     """One label set's state.  ``kind`` decides which fields are live."""
 
@@ -109,12 +120,7 @@ class Family:
             c.value = v
 
     def observe(self, labelvalues: tuple, seconds: float) -> None:
-        c = self.child(labelvalues)
-        idx = bucket_index_us(seconds * 1e6)
-        with c.lock:
-            c.buckets[idx] += 1
-            c.sum += seconds
-            c.count += 1
+        observe_child(self.child(labelvalues), seconds)
 
     # -- reads -------------------------------------------------------------
 

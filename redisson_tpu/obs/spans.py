@@ -16,16 +16,204 @@ includes any launch-slot wait plus the enqueue itself.  The
 device-dispatch phase additionally runs under a
 ``jax.profiler.TraceAnnotation`` (see executor/coalescer.py), so a
 captured device trace correlates with these host spans by name.
+
+Host regions (``region``): every thread on the launch path names its
+work, and the waits that hold a launch back, as a
+``jax.profiler.TraceAnnotation`` whose name is a constant starting with
+``rtpu:`` (the catalog is in docs/observability.md).  With no capture
+active a region costs one ``TraceMe.is_enabled()`` check and returns a
+shared no-op context.  A region's start in the capture equals
+``time.monotonic_ns()`` at its entry less one constant, so the span
+stamps here and the capture's host rows share a clock up to an offset.
 """
 
 from __future__ import annotations
 
+import contextlib
+import gc
 import threading
 import time
+import weakref
 from collections import deque
 from typing import Optional
 
+from redisson_tpu.obs.registry import observe_child
+
+try:
+    from jaxlib._profiler import TraceMe as _TraceMe
+
+    _capturing = _TraceMe.is_enabled
+except ImportError:  # pragma: no cover — jaxlib ships with jax
+    def _capturing() -> bool:
+        return False
+
 PHASES = ("coalesce_wait", "host_stage", "device_dispatch", "d2h_fetch")
+
+_NULL = contextlib.nullcontext()
+_annotation = None
+
+
+def region(name: str):
+    """The host region ``name`` while a profiler capture is active, else
+    a shared no-op context.  ``name`` must be a constant: no ids, keys or
+    counts, so gap names stay comparable from run to run."""
+    if not _capturing():
+        return _NULL
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation(name)
+
+
+class Timed:
+    """Times a block (monotonic clock) into one histogram child and, while
+    a capture is active, names it as the host region ``name``."""
+
+    __slots__ = ("_child", "_name", "_t0", "_ann")
+
+    def __init__(self, child, name: str):
+        self._child = child
+        self._name = name
+        self._ann = None
+
+    def __enter__(self):
+        if _capturing():
+            self._ann = region(self._name)
+            self._ann.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, et, ev, tb):
+        observe_child(self._child, time.monotonic() - self._t0)
+        if self._ann is not None:
+            self._ann.__exit__(et, ev, tb)
+        return False
+
+
+class WaitRegionLock:
+    """A lock whose CONTENDED acquisitions are the host region ``name``
+    (an uncontended one costs one non-blocking try).  Drop-in for the
+    wrapped Lock/RLock's ``acquire`` / ``release`` / ``with``."""
+
+    __slots__ = ("_lock", "_name")
+
+    def __init__(self, lock, name: str):
+        self._lock = lock
+        self._name = name
+
+    def acquire(self, blocking: bool = True, timeout: float = -1) -> bool:
+        if self._lock.acquire(False):
+            return True
+        if not blocking:
+            return False
+        with region(self._name):
+            return self._lock.acquire(True, timeout)
+
+    def release(self) -> None:
+        self._lock.release()
+
+    def __enter__(self):
+        self.acquire()
+        return self
+
+    def __exit__(self, *exc):
+        self._lock.release()
+        return False
+
+
+class _Dispatching(threading.local):
+    """What the executor is dispatching on this thread: its method name,
+    the bundle recording it, and the H2D bytes its staging helpers have
+    shipped so far.  Read by the compile listener to label compiles."""
+
+    method: Optional[str] = None
+    obs = None
+    h2d_bytes = 0
+
+
+DISPATCH = _Dispatching()
+
+# -- process-wide hooks: one GC callback and one compile listener feed
+# every live Observability bundle -------------------------------------------
+
+_BUNDLES: "weakref.WeakSet" = weakref.WeakSet()
+_hooks_lock = threading.Lock()
+_gc_hooked = False
+_compile_hooked = False
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+class _GcHook:
+    """``gc.callbacks`` member: the collection is the host region
+    ``rtpu:gc`` on the thread that triggered it, and its pause lands in
+    every live bundle's ``rtpu_gc_pause_seconds`` /
+    ``rtpu_gc_collections``.  Collections never overlap (the collector
+    runs one at a time), so one start stamp suffices."""
+
+    def __init__(self):
+        self._t0 = None
+        self._ann = None
+
+    def __call__(self, phase, info):
+        if phase == "start":
+            self._t0 = time.monotonic()
+            if _capturing():
+                self._ann = region("rtpu:gc")
+                self._ann.__enter__()
+            return
+        t0, self._t0 = self._t0, None
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        if t0 is None:
+            return
+        dur = time.monotonic() - t0
+        gen = (str(info["generation"]),)
+        for b in list(_BUNDLES):
+            b.gc_pause_seconds.inc((), dur)
+            b.gc_collections.inc(gen)
+
+
+def _on_duration(event: str, secs: float, **kw) -> None:
+    """jax.monitoring listener: a backend compile is labelled by the
+    executor method dispatching on this thread, and counted in that
+    method's bundle; one outside any dispatch counts in every live
+    bundle under ``other``."""
+    if event != COMPILE_EVENT:
+        return
+    d = DISPATCH
+    obs = d.obs
+    targets = (obs,) if obs is not None else tuple(_BUNDLES)
+    labels = (d.method or "other",)
+    for b in targets:
+        b.compiles.inc(labels)
+        b.compile_seconds.inc((), secs)
+
+
+def register_bundle(obs) -> None:
+    """Make ``obs`` a target of the process-wide GC hook (installed on the
+    first call)."""
+    global _gc_hooked
+    _BUNDLES.add(obs)
+    with _hooks_lock:
+        if not _gc_hooked:
+            gc.callbacks.append(_GcHook())
+            _gc_hooked = True
+
+
+def install_compile_listener() -> None:
+    """Register the compile listener with ``jax.monitoring`` once per
+    process (called where JAX is already loaded: the executor)."""
+    global _compile_hooked
+    with _hooks_lock:
+        if _compile_hooked:
+            return
+        import jax
+
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _compile_hooked = True
 
 
 class OpSpan:

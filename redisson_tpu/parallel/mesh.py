@@ -34,6 +34,14 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from redisson_tpu.ops import bitops, bloom, hll as hll_ops
 
 
+def _named_jit(fn, name: str, **kw):
+    """``jax.jit`` whose XLA module is ``jit_<name>``: every device op
+    in a trace then names the builder that made it (a shard_map body is
+    otherwise ``inner`` in every program)."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, **kw)
+
+
 class MeshContext:
     """Owns the device mesh and sharding specs (the ConnectionManager-role
     object for the device 'cluster', → SURVEY.md §2.4)."""
@@ -95,7 +103,7 @@ def sharded_bloom_add(ctx: MeshContext, *, k: int, words_per_row: int, pack_resu
         in_specs=(P("shard"), P(), P(), P(), P(), P()),
         out_specs=(P("shard"), P()),
     )
-    return jax.jit(fn, donate_argnums=(0,))
+    return _named_jit(fn, "sharded_bloom_add", donate_argnums=(0,))
 
 
 def sharded_bloom_contains(ctx: MeshContext, *, k: int, words_per_row: int, pack_results: bool = False):
@@ -120,7 +128,7 @@ def sharded_bloom_contains(ctx: MeshContext, *, k: int, words_per_row: int, pack
         in_specs=(P("shard"), P(), P(), P(), P(), P()),
         out_specs=P(),
     )
-    return jax.jit(fn)
+    return _named_jit(fn, "sharded_bloom_contains")
 
 
 # --------------------------------------------------------------------------
@@ -144,7 +152,7 @@ def sharded_hll_add(ctx: MeshContext):
         in_specs=(P("shard"), P(), P(), P(), P(), P()),
         out_specs=P("shard"),
     )
-    return jax.jit(fn, donate_argnums=(0,))
+    return _named_jit(fn, "sharded_hll_add", donate_argnums=(0,))
 
 
 def sharded_hll_histogram(ctx: MeshContext):
@@ -162,7 +170,7 @@ def sharded_hll_histogram(ctx: MeshContext):
     fn = jax.shard_map(
         inner, mesh=ctx.mesh, in_specs=(P("shard"), P()), out_specs=P()
     )
-    return jax.jit(fn)
+    return _named_jit(fn, "sharded_hll_histogram")
 
 
 # --------------------------------------------------------------------------
@@ -200,7 +208,7 @@ def sharded_mbit_set(ctx: MeshContext, *, words_local: int):
         in_specs=(P("shard"), P(), P()),
         out_specs=(P("shard"), P()),
     )
-    return jax.jit(fn, donate_argnums=(0,))
+    return _named_jit(fn, "sharded_mbit_set", donate_argnums=(0,))
 
 
 def sharded_mbit_get(ctx: MeshContext, *, words_local: int):
@@ -222,7 +230,7 @@ def sharded_mbit_get(ctx: MeshContext, *, words_local: int):
     fn = jax.shard_map(
         inner, mesh=ctx.mesh, in_specs=(P("shard"), P()), out_specs=P()
     )
-    return jax.jit(fn)
+    return _named_jit(fn, "sharded_mbit_get")
 
 
 # --------------------------------------------------------------------------
@@ -237,10 +245,12 @@ def sharded_mbit_get(ctx: MeshContext, *, words_local: int):
 # --------------------------------------------------------------------------
 
 
-def _psharded(ctx: MeshContext, inner, n_op_args: int, *, out_state: bool, donate: bool = True):
+def _psharded(ctx: MeshContext, inner, n_op_args: int, *, out_state: bool, donate: bool = True,
+              name: str = "psharded"):
     """shard_map wrapper for partitioned op batches: ``inner(local_state,
     *op_cols)`` sees one shard's [Bp]-shaped columns and returns
-    (new_local, res[Bp-packed]) or just res."""
+    (new_local, res[Bp-packed]) or just res.  ``name`` names the XLA
+    module (``jit_<name>``): the calling builder's name."""
 
     def wrapped(state, *ops):
         local = state[0]
@@ -254,7 +264,7 @@ def _psharded(ctx: MeshContext, inner, n_op_args: int, *, out_state: bool, donat
         in_specs=(P("shard"),) + (P("shard"),) * n_op_args,
         out_specs=out_specs,
     )
-    return jax.jit(fn, donate_argnums=(0,) if (out_state and donate) else ())
+    return _named_jit(fn, name, donate_argnums=(0,) if (out_state and donate) else ())
 
 
 def psharded_bloom_mixed(ctx: MeshContext, *, k: int, words_per_row: int):
@@ -268,7 +278,7 @@ def psharded_bloom_mixed(ctx: MeshContext, *, k: int, words_per_row: int):
         )
         return new_local[None], bitops.pack_bool_u32(res)[None]
 
-    return _psharded(ctx, inner, 6, out_state=True)
+    return _psharded(ctx, inner, 6, out_state=True, name="psharded_bloom_mixed")
 
 
 def psharded_bloom_mixed_keys(ctx: MeshContext, *, k: int, words_per_row: int, target_lanes: int):
@@ -284,7 +294,7 @@ def psharded_bloom_mixed_keys(ctx: MeshContext, *, k: int, words_per_row: int, t
         )
         return new_local[None], bitops.pack_bool_u32(res)[None]
 
-    return _psharded(ctx, inner, 6, out_state=True)
+    return _psharded(ctx, inner, 6, out_state=True, name="psharded_bloom_mixed_keys")
 
 
 def psharded_bitset_mixed(ctx: MeshContext, *, words_per_row: int):
@@ -296,7 +306,7 @@ def psharded_bitset_mixed(ctx: MeshContext, *, words_per_row: int):
         )
         return new_local[None], bitops.pack_bool_u32(obs)[None]
 
-    return _psharded(ctx, inner, 4, out_state=True)
+    return _psharded(ctx, inner, 4, out_state=True, name="psharded_bitset_mixed")
 
 
 def psharded_bitset_rw(ctx: MeshContext, kernel, *, words_per_row: int):
@@ -306,7 +316,7 @@ def psharded_bitset_rw(ctx: MeshContext, kernel, *, words_per_row: int):
         )
         return new_local[None], bitops.pack_bool_u32(prev)[None]
 
-    return _psharded(ctx, inner, 3, out_state=True)
+    return _psharded(ctx, inner, 3, out_state=True, name="psharded_bitset_rw")
 
 
 def psharded_bitset_get(ctx: MeshContext, *, words_per_row: int):
@@ -318,7 +328,7 @@ def psharded_bitset_get(ctx: MeshContext, *, words_per_row: int):
         )
         return bitops.pack_bool_u32(res & valid)[None]
 
-    return _psharded(ctx, inner, 3, out_state=False)
+    return _psharded(ctx, inner, 3, out_state=False, name="psharded_bitset_get")
 
 
 def psharded_hll_add_changed(ctx: MeshContext):
@@ -328,7 +338,7 @@ def psharded_hll_add_changed(ctx: MeshContext):
         )
         return new_local[None], bitops.pack_bool_u32(changed)[None]
 
-    return _psharded(ctx, inner, 5, out_state=True)
+    return _psharded(ctx, inner, 5, out_state=True, name="psharded_hll_add_changed")
 
 
 def psharded_hll_add_keys(ctx: MeshContext, *, target_lanes: int):
@@ -346,7 +356,7 @@ def psharded_hll_add_keys(ctx: MeshContext, *, target_lanes: int):
         )
         return new_local[None], bitops.pack_bool_u32(changed)[None]
 
-    return _psharded(ctx, inner, 4, out_state=True)
+    return _psharded(ctx, inner, 4, out_state=True, name="psharded_hll_add_keys")
 
 
 def psharded_cms_update_estimate(ctx: MeshContext, *, d: int, w: int, cells_per_row: int, estimate_only: bool = False, update_only: bool = False):
@@ -372,7 +382,7 @@ def psharded_cms_update_estimate(ctx: MeshContext, *, d: int, w: int, cells_per_
         return new_local[None], est[None]
 
     if estimate_only:
-        return _psharded(ctx, inner, 5, out_state=False)
+        return _psharded(ctx, inner, 5, out_state=False, name="psharded_cms_update_estimate")
     if update_only:
         def wrapped(state, *ops):
             return inner(state[0], *[o[0] for o in ops])
@@ -382,8 +392,8 @@ def psharded_cms_update_estimate(ctx: MeshContext, *, d: int, w: int, cells_per_
             in_specs=(P("shard"),) * 6,
             out_specs=P("shard"),
         )
-        return jax.jit(fn, donate_argnums=(0,))
-    return _psharded(ctx, inner, 5, out_state=True)
+        return _named_jit(fn, "psharded_cms_update_estimate", donate_argnums=(0,))
+    return _psharded(ctx, inner, 5, out_state=True, name="psharded_cms_update_estimate")
 
 
 # --------------------------------------------------------------------------
@@ -410,7 +420,7 @@ def msharded_row_map(ctx: MeshContext, fn_local):
     fn = jax.shard_map(
         inner, mesh=ctx.mesh, in_specs=(P("shard"), P()), out_specs=P("shard")
     )
-    return jax.jit(fn)
+    return _named_jit(fn, "msharded_row_map")
 
 
 def msharded_row_write(ctx: MeshContext, *, words_local: int):
@@ -426,7 +436,7 @@ def msharded_row_write(ctx: MeshContext, *, words_local: int):
         in_specs=(P("shard"), P(), P("shard")),
         out_specs=P("shard"),
     )
-    return jax.jit(fn, donate_argnums=(0,))
+    return _named_jit(fn, "msharded_row_write", donate_argnums=(0,))
 
 
 def msharded_set_range(ctx: MeshContext, *, words_local: int, value: bool):
@@ -446,7 +456,7 @@ def msharded_set_range(ctx: MeshContext, *, words_local: int, value: bool):
         in_specs=(P("shard"), P(), P("shard"), P("shard")),
         out_specs=P("shard"),
     )
-    return jax.jit(fn, donate_argnums=(0,))
+    return _named_jit(fn, "msharded_set_range", donate_argnums=(0,))
 
 
 def msharded_bitop(ctx: MeshContext, *, words_local: int, op: str, n_src: int, masked: bool = False):
@@ -470,7 +480,7 @@ def msharded_bitop(ctx: MeshContext, *, words_local: int, op: str, n_src: int, m
         in_specs=(P("shard"), P(), P(), P("shard")),
         out_specs=P("shard"),
     )
-    return jax.jit(fn, donate_argnums=(0,))
+    return _named_jit(fn, "msharded_bitop", donate_argnums=(0,))
 
 
 # --------------------------------------------------------------------------
@@ -508,7 +518,7 @@ def sharded_hll_merge(ctx: MeshContext):
     fn = jax.shard_map(
         inner, mesh=ctx.mesh, in_specs=(P("shard"), P(), P()), out_specs=P("shard")
     )
-    return jax.jit(fn, donate_argnums=(0,))
+    return _named_jit(fn, "sharded_hll_merge", donate_argnums=(0,))
 
 
 def sharded_bitop(ctx: MeshContext, *, words_per_row: int, op: str, n_src: int, masked: bool = False):
@@ -559,7 +569,7 @@ def sharded_bitop(ctx: MeshContext, *, words_per_row: int, op: str, n_src: int, 
         in_specs=(P("shard"), P(), P(), P()),
         out_specs=P("shard"),
     )
-    return jax.jit(fn, donate_argnums=(0,))
+    return _named_jit(fn, "sharded_bitop", donate_argnums=(0,))
 
 
 # --------------------------------------------------------------------------
@@ -589,7 +599,7 @@ def sharded_bitset_set_range(ctx: MeshContext, *, words_per_row: int, value: boo
         in_specs=(P("shard"), P(), P(), P()),
         out_specs=P("shard"),
     )
-    return jax.jit(fn, donate_argnums=(0,))
+    return _named_jit(fn, "sharded_bitset_set_range", donate_argnums=(0,))
 
 
 def sharded_row_reduce(ctx: MeshContext, fn_local):
@@ -609,7 +619,7 @@ def sharded_row_reduce(ctx: MeshContext, fn_local):
     fn = jax.shard_map(
         inner, mesh=ctx.mesh, in_specs=(P("shard"), P()), out_specs=P()
     )
-    return jax.jit(fn)
+    return _named_jit(fn, "sharded_row_reduce")
 
 
 def sharded_row_read(ctx: MeshContext, *, row_units: int):
@@ -629,7 +639,7 @@ def sharded_row_read(ctx: MeshContext, *, row_units: int):
     fn = jax.shard_map(
         inner, mesh=ctx.mesh, in_specs=(P("shard"), P()), out_specs=P()
     )
-    return jax.jit(fn)
+    return _named_jit(fn, "sharded_row_read")
 
 
 def sharded_row_write(ctx: MeshContext, *, row_units: int):
@@ -651,7 +661,7 @@ def sharded_row_write(ctx: MeshContext, *, row_units: int):
         in_specs=(P("shard"), P(), P()),
         out_specs=P("shard"),
     )
-    return jax.jit(fn, donate_argnums=(0,))
+    return _named_jit(fn, "sharded_row_write", donate_argnums=(0,))
 
 
 def sharded_cms_merge(ctx: MeshContext, *, cells_per_row: int):
@@ -678,4 +688,4 @@ def sharded_cms_merge(ctx: MeshContext, *, cells_per_row: int):
     fn = jax.shard_map(
         inner, mesh=ctx.mesh, in_specs=(P("shard"), P(), P()), out_specs=P("shard")
     )
-    return jax.jit(fn, donate_argnums=(0,))
+    return _named_jit(fn, "sharded_cms_merge", donate_argnums=(0,))

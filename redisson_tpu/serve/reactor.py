@@ -47,6 +47,7 @@ import traceback
 from collections import deque
 
 from redisson_tpu.analysis import witness as _witness
+from redisson_tpu.obs.spans import region as _region
 from redisson_tpu.serve.resp import (
     ProtocolError,
     _ConnCtx,
@@ -386,32 +387,38 @@ class _Reactor(threading.Thread):
         self._wake_w.close()
 
     def _tick(self) -> None:
+        # Host regions (docs/observability.md): the idle select is left
+        # unnamed; the reads, the deliberate gather wait, the pass and
+        # the writes are rtpu:resp:*.
         timeout = 0.0 if self._work_ready() else _TICK_S
         events = self.sel.select(timeout)
         gathers = _GATHER_MAX if events and len(self.conns) > 2 else 0
         while True:
             now = time.monotonic()
-            for key, mask in events:
-                rconn = key.data
-                if rconn is None:
-                    self._drain_wake()
-                    continue
-                if rconn.closing:
-                    self._close_conn(rconn)  # async close: finish it
-                    continue
-                if mask & selectors.EVENT_WRITE:
-                    self._flush(rconn)
-                if mask & selectors.EVENT_READ:
-                    self._read_ready(rconn, now)
+            with _region("rtpu:resp:read"):
+                for key, mask in events:
+                    rconn = key.data
+                    if rconn is None:
+                        self._drain_wake()
+                        continue
+                    if rconn.closing:
+                        self._close_conn(rconn)  # async close: finish it
+                        continue
+                    if mask & selectors.EVENT_WRITE:
+                        self._flush(rconn)
+                    if mask & selectors.EVENT_READ:
+                        self._read_ready(rconn, now)
             if gathers <= 0:
                 break
             gathers -= 1
-            events = self.sel.select(_GATHER_S)
+            with _region("rtpu:resp:gather"):
+                events = self.sel.select(_GATHER_S)
             if not events:
                 break
         self._admit_new()
         self._apply_write_interest()
-        self._run_pass(now)
+        with _region("rtpu:resp:run"):
+            self._run_pass(now)
         if now - self._last_sweep >= self._sweep_interval():
             self._last_sweep = now
             self._sweep(now)
@@ -750,7 +757,8 @@ class _Reactor(threading.Thread):
         The connection is frozen (busy) until this completes, so its
         ordering is exactly the thread path's."""
         try:
-            frame = self.server._safe_dispatch(cmd, rconn.ctx)
+            with _region("rtpu:resp:detached"):
+                frame = self.server._safe_dispatch(cmd, rconn.ctx)
             if frame:
                 rconn.ctx.send(frame)
         except BaseException:  # _safe_dispatch already maps everything
@@ -773,7 +781,7 @@ class _Reactor(threading.Thread):
         """Send as much of the backlog as the socket accepts (reactor
         thread only)."""
         dead = False
-        with rconn.wlock:
+        with _region("rtpu:resp:write"), rconn.wlock:
             buf = rconn.outbuf
             while buf:
                 try:
