@@ -127,6 +127,9 @@ def warm_bloom_mixed_keys(k: int, L: int, Lt: int) -> Callable:
 
 
 def warm_bloom_mixed_keys_runs(k: int, L: int, Lt: int, const_len: bool) -> Callable:
+    """Both run-length keyed kernels at each bucket: the contains-only
+    gather (bloom_contains_keys_runs) and the scan that segments holding
+    an add take (bloom_mixed_keys_runs, which scans whatever its flags)."""
     def fn(ex, wpool, B):
         if not getattr(ex, "supports_runs_metadata", False):
             return  # rebound to a sharded successor: no runs kernel
@@ -135,11 +138,12 @@ def warm_bloom_mixed_keys_runs(k: int, L: int, Lt: int, const_len: bool) -> Call
         lengths = (
             np.uint32(Lt * 4) if const_len else np.full(B, Lt * 4, np.uint32)
         )
+        runs = (np.zeros(1, np.int32), np.ones(1, np.uint32))
+        starts = np.array([0, B], np.int32)
+        _raw(ex, "bloom_contains_keys_runs")(
+            ex, wpool, k, blocks, lengths, *runs, starts)
         _raw(ex, "bloom_mixed_keys_runs")(
-            ex, wpool, k, blocks, lengths,
-            np.zeros(1, np.int32), np.ones(1, np.uint32),
-            np.zeros(1, bool), np.array([0, B], np.int32),
-        )
+            ex, wpool, k, blocks, lengths, *runs, np.zeros(1, bool), starts)
     return fn
 
 

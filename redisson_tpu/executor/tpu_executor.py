@@ -254,6 +254,23 @@ class _StagingRings(threading.local):
         return slot
 
 
+def _expand_runs(starts, n: int, *cols):
+    """Per-op values of run-length columns, inside a jit: op i < n takes
+    run j's value where ``starts[j] <= i < starts[j + 1]``; ops at or
+    past the last start take the last run's.  ``starts``: int32[C + 1]
+    non-decreasing, ``cols``: [C] each.  Each run start adds the step
+    from the previous run's value and a prefix sum spreads it (modular
+    integer steps, so exact); a searchsorted in its place was a while
+    loop of element gathers, about 60 ms per 1M-op launch on v5e."""
+    pos = starts[1:-1]
+    out = []
+    for col in cols:
+        z = jnp.zeros((n,), col.dtype).at[pos].add(col[1:] - col[:-1],
+                                                   mode="drop")
+        out.append(col[0] + jnp.cumsum(z, dtype=col.dtype))
+    return out
+
+
 def _fill_words(buf, off: int, n_pad: int, arr, dtype, fill=0) -> int:
     """Write ``arr`` into buf[off:off+n_pad] viewed as a 4-byte ``dtype``,
     padding the tail with ``fill``; returns the next offset."""
@@ -768,30 +785,39 @@ class TpuCommandExecutor:
     def bloom_mixed_keys_runs(self, pool, k: int, blocks, lengths, run_rows, run_m, run_flags, run_starts) -> LazyResult:
         """Coalesced mixed path with RUN-LENGTH metadata: per-op rows/m/is_add/valid are constant within
         each submitted chunk, so they ship once per run (C entries + C+1
-        cumulative starts) and expand to per-op arrays ON DEVICE via
-        searchsorted — cutting link bytes/op from ~22-30 to ~8-12 on the
+        cumulative starts) and expand to per-op arrays ON DEVICE
+        (_expand_runs) — cutting link bytes/op from ~22-30 to ~8-12 on the
         config-4 mixed path.  ``lengths``: uint32 scalar when every op in
         the launch shares one key length (the common codec case), else a
         per-op array.  ``run_starts[i]``: first op index of run i;
         ``run_starts[C]`` = total real ops (ops beyond it are padding)."""
-        B = int(run_starts[-1])
-        Bp = self._bucket(B)
-        blocks, L = self._trim_lanes(blocks)
-        Lt = blocks.shape[1]
-        C = len(run_rows)
-        # One compiled shape for any C ≤ 1024 (the padded runs cost ~13KB
-        # on the wire — noise); degenerate many-tiny-chunk segments grow
-        # the bucket rather than fail.
-        Cp = max(1024, _pow2ceil(C))
-        wpr = pool.row_units
-        Wc = Cp >> 5
-        const_len = np.ndim(lengths) == 0
-        key = ("bloom_mixk_runs", wpr, pool.state.shape[0], Bp, k, L, Lt, Cp, const_len)
+        return self._keys_runs_launch(
+            pool, k, blocks, lengths, run_rows, run_m, run_flags, run_starts)
+
+    def bloom_contains_keys_runs(self, pool, k: int, blocks, lengths, run_rows, run_m, run_starts) -> LazyResult:
+        """Contains-only twin of bloom_mixed_keys_runs (same run-length
+        layout, no flag words): with no add in the launch every op sees
+        the pre-launch state, so one hashed gather
+        (fastpath.bloom_contains_keys) answers exactly what the
+        sort-chunked scan would.  Reads the state; donates nothing."""
+        return self._keys_runs_launch(
+            pool, k, blocks, lengths, run_rows, run_m, None, run_starts)
+
+    def _keys_runs_program(self, writes: bool, shape: tuple):
+        """(jitted program, packed words, jit key) of one run-length keyed
+        Bloom kernel: the scan (``writes``: fastpath.bloom_mixed_keys,
+        donates the state, returns (new state, packed results)) or the
+        contains gather (fastpath.bloom_contains_keys, reads the state,
+        returns packed results).  ``shape`` = (words per row, state
+        length, Bp, k, L, Lt, Cp, const_len) fixes the packed layout."""
+        wpr, _, Bp, k, L, Lt, Cp, const_len = shape
+        Wc = Cp >> 5 if writes else 0
+        key = ("bloom_mixk_runs" if writes else "bloom_conk_runs",) + shape
 
         def build():
             def f(state, packed):
                 # Packed layout (one fused H2D per flush): [0]=n_ops,
-                # [1]=const key length, then starts/rr/rm/rf-bits
+                # [1]=const key length, then starts/rr/rm[/rf-bits]
                 # [/lengths]/blocks at the static offsets below.
                 n_ops = jax.lax.bitcast_convert_type(packed[0], jnp.int32)
                 o = 2
@@ -803,32 +829,55 @@ class TpuCommandExecutor:
                 o += Cp
                 rm = packed[o : o + Cp]
                 o += Cp
-                rf = bitops.unpack_bool_u32_dev(packed[o : o + Wc], Cp)
-                o += Wc
+                if writes:
+                    rf = bitops.unpack_bool_u32_dev(packed[o : o + Wc], Cp)
+                    o += Wc
                 if const_len:
                     lengths = packed[1]
                 else:
                     lengths = packed[o : o + Bp]
                     o += Bp
                 blocks = packed[o : o + Bp * Lt].reshape(Bp, Lt)
-                iota = jax.lax.iota(jnp.int32, Bp)
-                # Run index of op i = #(run ends ≤ i); padded ends equal
-                # n_ops, so tail ops clip to the last run (valid=False
-                # routes them to scratch).
-                seg = jnp.minimum(
-                    jnp.searchsorted(starts[1:], iota, side="right"), Cp - 1
-                )
+                # Padded run starts equal n_ops, so tail ops take run
+                # Cp - 1's row and m; the scan routes them to scratch
+                # (valid=False), the gather's results for them drop.
+                if not writes:
+                    rows, m = _expand_runs(starts, Bp, rr, rm)
+                    return bitops.pack_bool_u32(fastpath.bloom_contains_keys(
+                        state, rows, blocks, lengths, m,
+                        k=k, words_per_row=wpr, target_lanes=L,
+                    ))
+                rows, m, is_add = _expand_runs(
+                    starts, Bp, rr, rm, rf.astype(jnp.int32))
                 new, res = fastpath.bloom_mixed_keys(
-                    state, rr[seg], blocks, lengths, rm[seg], rf[seg],
-                    iota < n_ops, k=k, words_per_row=wpr, target_lanes=L,
+                    state, rows, blocks, lengths, m, is_add.astype(bool),
+                    jax.lax.iota(jnp.int32, Bp) < n_ops,
+                    k=k, words_per_row=wpr, target_lanes=L,
                 )
                 return new, bitops.pack_bool_u32(res)
             return f
 
-        fn = self._jit(key, build, donate=True)
         total = 2 + (Cp + 1) + 2 * Cp + Wc + (0 if const_len else Bp) + Bp * Lt
-        slot = self._staging.acquire(
-            ("bloom_mixk_runs", Bp, Lt, Cp, const_len), total)
+        return self._jit(key, build, donate=writes), total, key
+
+    def _keys_runs_launch(self, pool, k: int, blocks, lengths, run_rows, run_m, run_flags, run_starts) -> LazyResult:
+        """Host packing of the run-length keyed Bloom launches: the scan,
+        or the contains gather when ``run_flags`` is None (no flag words
+        ship)."""
+        B = int(run_starts[-1])
+        Bp = self._bucket(B)
+        blocks, L = self._trim_lanes(blocks)
+        Lt = blocks.shape[1]
+        C = len(run_rows)
+        # One compiled shape for any C ≤ 1024 (the padded runs cost ~13KB
+        # on the wire — noise); degenerate many-tiny-chunk segments grow
+        # the bucket rather than fail.
+        Cp = max(1024, _pow2ceil(C))
+        const_len = np.ndim(lengths) == 0
+        writes = run_flags is not None
+        shape = (pool.row_units, pool.state.shape[0], Bp, k, L, Lt, Cp, const_len)
+        fn, total, key = self._keys_runs_program(writes, shape)
+        slot = self._staging.acquire((key[0], Bp, Lt, Cp, const_len), total)
         buf = slot.buf
         buf[0] = B
         buf[1] = np.uint32(lengths) if const_len else 0
@@ -839,12 +888,15 @@ class TpuCommandExecutor:
         o += Cp + 1
         o = _fill_words(buf, o, Cp, np.asarray(run_rows, np.int32), np.int32)
         o = _fill_words(buf, o, Cp, np.asarray(run_m, np.uint32), np.uint32, 1)
-        o = _fill_bits(buf, o, Cp, run_flags)
+        if writes:
+            o = _fill_bits(buf, o, Cp, run_flags)
         if not const_len:
             o = _fill_words(
                 buf, o, Bp, np.asarray(lengths, np.uint32), np.uint32)
         _fill_blocks(buf, o, Bp, blocks)
-        pool.state, res = fn(pool.state, self._ship(slot, total))
+        res = fn(pool.state, self._ship(slot, total))
+        if writes:
+            pool.state, res = res
         return LazyResult(res, transform=lambda v: bitops.unpack_bool_u32(v, B))
 
     def bitset_mixed_runs(self, pool, idx, run_rows, run_ops, run_starts) -> LazyResult:
@@ -873,13 +925,10 @@ class TpuCommandExecutor:
                     packed[o : o + Cp], jnp.int32)
                 o += Cp
                 ro = packed[o : o + Cp]
-                iota = jax.lax.iota(jnp.int32, Bp)
-                seg = jnp.minimum(
-                    jnp.searchsorted(starts[1:], iota, side="right"), Cp - 1
-                )
+                rows, ops = _expand_runs(starts, Bp, rr, ro)
                 new, obs = bitset_ops.bitset_mixed(
-                    state, rr[seg], idx, ro[seg],
-                    words_per_row=wpr, valid=iota < n_ops,
+                    state, rows, idx, ops, words_per_row=wpr,
+                    valid=jax.lax.iota(jnp.int32, Bp) < n_ops,
                 )
                 return new, bitops.pack_bool_u32(obs)
             return f
@@ -1747,6 +1796,7 @@ DISPATCH_METHODS = (
     "bloom_mixed",
     "bloom_mixed_keys",
     "bloom_mixed_keys_runs",
+    "bloom_contains_keys_runs",
     "bitset_mixed",
     "bitset_mixed_runs",
     "bloom_add_fast_st",

@@ -1659,7 +1659,10 @@ class TpuSketchEngine(SketchDurabilityMixin):
         segment's per-chunk metas into per-RUN metadata arrays (row, m,
         is_add once per chunk + cumulative starts) and ships them with the
         concatenated key blocks (executor.bloom_mixed_keys_runs).  Key
-        lengths collapse to one scalar when every chunk is const-length."""
+        lengths collapse to one scalar when every chunk is const-length.
+        A segment with no add run takes the one-gather contains kernel
+        (executor.bloom_contains_keys_runs): nothing in it writes, so
+        every op sees the pre-launch state, as the scan would give."""
 
         def dispatch(cols, metas):
             C = len(metas)
@@ -1715,6 +1718,10 @@ class TpuSketchEngine(SketchDurabilityMixin):
                     lengths = np.full(B, lengths, np.uint32)
                 return self.executor.bloom_mixed_keys(
                     pool, rows, m_arr, k, cols[0], lengths, flags
+                )
+            if not run_flags.any():
+                return self.executor.bloom_contains_keys_runs(
+                    pool, k, cols[0], lengths, run_rows, run_m, starts
                 )
             return self.executor.bloom_mixed_keys_runs(
                 pool, k, cols[0], lengths, run_rows, run_m, run_flags, starts

@@ -138,18 +138,29 @@ def segmented_exclusive_max(first: jnp.ndarray, vals: jnp.ndarray):
     return jnp.where(first, vals * 0, exc)
 
 
+def lane_view(flat: jnp.ndarray):
+    """The [R, 128] row-gather view of a pool's words, scratch element
+    dropped.  Built once outside a loop: inside one, the slice copies
+    the whole pool on every step."""
+    n = flat.shape[0] - 1
+    return flat[:-1].reshape(n // 128, 128)
+
+
 def gather_words(flat: jnp.ndarray, gidx: jnp.ndarray):
     """Element gather from a flat pool array via the [R, 128] row-gather
     form (see gather_bits).  Works for any dtype; exact equivalent of
-    ``flat[gidx]`` for in-range indexes."""
-    n = flat.shape[0] - 1
-    if n % 128 != 0:
+    ``flat[gidx]`` for in-range indexes.  ``flat`` may also be the
+    pool's ``lane_view``."""
+    if flat.ndim == 2:
+        x2d = flat
+    elif (flat.shape[0] - 1) % 128 != 0:
         return flat[gidx]
-    x2d = flat[:-1].reshape(n // 128, 128)
+    else:
+        x2d = lane_view(flat)
     rows = jnp.take(x2d, (gidx >> _U7).astype(jnp.int32), axis=0)
     lane = (gidx & _U127).astype(jnp.int32)
     onehot = jnp.arange(128, dtype=jnp.int32)[None, :] == lane[:, None]
-    return jnp.sum(jnp.where(onehot, rows, 0), axis=1, dtype=flat.dtype)
+    return jnp.sum(jnp.where(onehot, rows, 0), axis=1, dtype=x2d.dtype)
 
 
 def _scatter_onehot(flat, gidx, values, combine: str):

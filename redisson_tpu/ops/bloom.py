@@ -26,8 +26,9 @@ def _op_words(rows, idx, words_per_row: int):
     return gword, idx & np.uint32(31)
 
 
-def bloom_contains(flat_words, rows, h1m, h2m, *, m: int, k: int, words_per_row: int):
-    """bool[B]: all k bits set per key."""
+def bloom_contains(flat_words, rows, h1m, h2m, *, m, k: int, words_per_row: int):
+    """bool[B]: all k bits set per key.  ``m``: a static int or a per-op
+    uint32[B] (see bitops.expand_km_indexes)."""
     idx = bitops.expand_km_indexes(h1m, h2m, m, k)  # [B, k]
     gword, bit = _op_words(rows[:, None], idx, words_per_row)
     bits = bitops.gather_bits(flat_words, gword.reshape(-1), bit.reshape(-1))

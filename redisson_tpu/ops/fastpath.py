@@ -23,8 +23,15 @@ from __future__ import annotations
 
 import numpy as np
 import jax.numpy as jnp
+from jax import lax
 
 from redisson_tpu.ops import bitops
+
+# Ops per gather slice of bloom_contains_keys: the intermediate holds
+# GATHER_SLICE * k rows of 512 bytes (42 MB at k = 5).  On one v5e a 1M-op
+# launch took 64 ms at 16,384 ops a slice, 73 at 65,536, 76 at 262,144
+# and 74 unsliced (pipelined, wall clock), so the shortest of those.
+GATHER_SLICE = 1 << 14
 
 
 def bloom_add_fast_st(flat_words, row, h1m, h2m, m, valid, *, k: int, words_per_row: int):
@@ -149,6 +156,34 @@ def bloom_contains_keys_st(flat_words, row, blocks, lengths, m, *, k: int, words
     return bloom_contains_st(
         flat_words, row, h1m, h2m, m, k=k, words_per_row=words_per_row
     )
+
+
+def bloom_contains_keys(flat_words, rows, blocks, lengths, m_arr, *, k: int, words_per_row: int, target_lanes: int):
+    """Multi-tenant contains from raw key lanes: device hash, then one
+    row gather (ops/bloom.bloom_contains) — no sort, no chunk scan, no
+    scatter, and the state is only read.  With no add in the batch every
+    op sees the pre-batch state, so this equals ``bloom_mixed_keys`` with
+    ``is_add`` all False bit for bit.  Padding ops read whatever word
+    their (row, m) lands on; the caller drops their results.
+
+    The row gather holds one 512-byte row per probe, so a batch longer
+    than ``GATHER_SLICE`` ops gathers slice by slice (``lax.map``) from
+    one lane view of the pool, which bounds that intermediate."""
+    from redisson_tpu.ops import bloom
+
+    h1m, h2m = _hash_km_device(blocks, lengths, m_arr, target_lanes)
+    B, S = rows.shape[0], GATHER_SLICE
+
+    def contains(words, rows, h1m, h2m, m):
+        return bloom.bloom_contains(
+            words, rows, h1m, h2m, m=m, k=k, words_per_row=words_per_row)
+
+    if B <= S:
+        return contains(flat_words, rows, h1m, h2m, m_arr)
+    lanes = bitops.lane_view(flat_words)
+    cols = [jnp.broadcast_to(c, (B,)).reshape(B // S, S)
+            for c in (rows, h1m, h2m, m_arr)]
+    return lax.map(lambda c: contains(lanes, *c), cols).reshape(B)
 
 
 def bloom_mixed_keys(flat_words, rows, blocks, lengths, m_arr, is_add, valid, *, k: int, words_per_row: int, target_lanes: int):

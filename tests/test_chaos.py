@@ -264,7 +264,7 @@ class TestBreaker:
 
 BLOOM_POINTS = (
     "dispatch.bloom_mixed", "dispatch.bloom_mixed_keys",
-    "dispatch.bloom_mixed_keys_runs",
+    "dispatch.bloom_mixed_keys_runs", "dispatch.bloom_contains_keys_runs",
 )
 
 

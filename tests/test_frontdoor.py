@@ -341,6 +341,7 @@ class TestDifferentialSoak:
             for point in (
                 "dispatch.bloom_mixed_keys",
                 "dispatch.bloom_mixed_keys_runs",
+                "dispatch.bloom_contains_keys_runs",
                 "dispatch.bitset_mixed",
                 "dispatch.bitset_mixed_runs",
             ):

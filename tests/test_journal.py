@@ -502,9 +502,9 @@ class TestRecoveryEdgeCases:
         bf.add(1)
         chaos.inject("dispatch.bloom_mixed", kind="error", rate=1.0)
         chaos.inject("dispatch.bloom_mixed_keys", kind="error", rate=1.0)
-        chaos.inject(
-            "dispatch.bloom_mixed_keys_runs", kind="error", rate=1.0
-        )
+        for point in ("dispatch.bloom_mixed_keys_runs",
+                      "dispatch.bloom_contains_keys_runs"):
+            chaos.inject(point, kind="error", rate=1.0)
         # Drive the breaker open (the first add surfaces the typed
         # failure), then every retried add lands mirror-acked.
         for i in range(100, 110):

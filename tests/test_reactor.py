@@ -535,6 +535,7 @@ class TestMultiConnDifferentialSoak:
             for point in (
                 "dispatch.bloom_mixed_keys",
                 "dispatch.bloom_mixed_keys_runs",
+                "dispatch.bloom_contains_keys_runs",
                 "dispatch.bitset_mixed",
                 "dispatch.bitset_mixed_runs",
                 "dispatch.cms_update_estimate",

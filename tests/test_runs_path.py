@@ -123,3 +123,27 @@ def test_runs_empty_batch():
         assert bf.contains_all(np.array([], dtype=np.uint64)) == 0
     finally:
         c.shutdown()
+
+
+@pytest.mark.parametrize("sizes", [(5, 0, 3, 8), (1,), (0, 0, 7, 1, 0)])
+def test_expand_runs_matches_repeat(sizes):
+    """The on-device run expansion equals np.repeat of each run's value,
+    empty runs included; ops past the last start take the last padded
+    run's value, and int32/uint32 steps that wrap stay exact."""
+    import jax.numpy as jnp
+
+    from redisson_tpu.executor.tpu_executor import _expand_runs
+
+    C, Cp, n = len(sizes), 8, 32
+    B = sum(sizes)
+    starts = np.full(Cp + 1, B, np.int32)
+    starts[: C + 1] = np.concatenate([[0], np.cumsum(sizes)])
+    rng = np.random.default_rng(C)
+    rows = rng.integers(-(1 << 31), 1 << 31, Cp, dtype=np.int64).astype(np.int32)
+    ms = rng.integers(0, 1 << 32, Cp, dtype=np.uint64).astype(np.uint32)
+    got = _expand_runs(jnp.asarray(starts), n, jnp.asarray(rows),
+                       jnp.asarray(ms))
+    for col, want in zip(got, (rows, ms)):
+        ref = np.concatenate([np.repeat(want[:C], sizes),
+                              np.full(n - B, want[Cp - 1])])
+        np.testing.assert_array_equal(np.asarray(col), ref)

@@ -124,10 +124,12 @@ def test_bloom_config1_row(ex, op):
     assert c.memory_analysis().argument_size_in_bytes >= 4 * pool.row_units
 
 
-@pytest.mark.parametrize("op", ["bloom_mixed", "bloom_mixed_keys_runs"])
+@pytest.mark.parametrize("op", ["bloom_mixed", "bloom_mixed_keys_runs",
+                                "bloom_contains_keys_runs"])
 def test_bloom_config4_stacked_pool(ex, op):
     """Config 4: 1000 stacked 10k/1% tenants (pool grown to 1024 rows),
-    one 64k-op mixed segment — host-hashed and device-hashed forms."""
+    one 64k-op segment — host-hashed and device-hashed mixed forms, and
+    the contains-only gather."""
     m, B, T = 95_851, 1 << 16, 1024
     pool = _pool(ex, PoolKind.BLOOM, (class_words_for_bits(m),), tenants=T)
     rng = np.random.default_rng(0)
@@ -140,12 +142,28 @@ def test_bloom_config4_stacked_pool(ex, op):
         C = B // 256  # one run per submitted 256-key chunk
         blocks = np.zeros((B, 4), np.uint32)  # LongCodec: 8-byte keys
         blocks[:, :2] = rng.integers(0, 1 << 32, (B, 2))
-        call = lambda: ex.bloom_mixed_keys_runs(  # noqa: E731
-            pool, K, blocks, np.uint32(8),
-            rng.integers(0, 1000, C).astype(np.int32),
-            np.full(C, m, np.uint32), rng.random(C) < 0.3,
+        runs = (rng.integers(0, 1000, C).astype(np.int32),
+                np.full(C, m, np.uint32))
+        flags = (rng.random(C) < 0.3,) if op == "bloom_mixed_keys_runs" else ()
+        call = lambda: getattr(ex, op)(  # noqa: E731
+            pool, K, blocks, np.uint32(8), *runs, *flags,
             np.arange(0, B + 1, 256, dtype=np.int32))
     _compile(call)
+
+
+def test_bloom_55m_contains_gather_memory(ex):
+    """The bulk contains shape: one 401,414,247-bit filter (8-row pool,
+    512 MB), a 1M-key contains-only launch.  The gather runs over op
+    slices, so its temporaries stay under 2 GB (1.6 GB as written);
+    one gather of all 5.2M probe rows holds 3.3 GB."""
+    m, B = 401_414_247, 1 << 20
+    pool = _pool(ex, PoolKind.BLOOM, (class_words_for_bits(m),))
+    blocks = np.zeros((B, 4), np.uint32)
+    blocks[:, :2] = np.random.default_rng(0).integers(0, 1 << 32, (B, 2))
+    c = _compile(lambda: ex.bloom_contains_keys_runs(
+        pool, 5, blocks, np.uint32(8), np.zeros(1, np.int32),
+        np.full(1, m, np.uint32), np.array([0, B], np.int32)))
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 31
 
 
 @pytest.mark.parametrize("op", ["hll_add", "hll_add_changed"])

@@ -52,7 +52,8 @@ def test_prewarm_populates_every_bucket():
 
 def test_prewarm_keyed_ladder_registers_on_first_submit():
     """The codec-shaped (lane count / trim depth) runs-path ladder can't
-    be known at pool attach; the FIRST encoded submit schedules it."""
+    be known at pool attach; the FIRST encoded submit schedules it, for
+    both run-length kernels: the contains-only gather and the scan."""
     cl = _client(prewarm=True, max_batch=1024)
     try:
         bf = cl.get_bloom_filter("pw-keyed")
@@ -61,8 +62,9 @@ def test_prewarm_keyed_ladder_registers_on_first_submit():
         assert cl.prewarm_wait(300)
         ex = cl._engine.executor
         ladder = set(cl._engine.prewarmer.ladder())
-        runs = {k[3] for k in ex._jit_cache if k[0] == "bloom_mixk_runs"}
-        assert ladder <= runs, (sorted(runs), sorted(ladder))
+        for op in ("bloom_mixk_runs", "bloom_conk_runs"):
+            runs = {k[3] for k in ex._jit_cache if k[0] == op}
+            assert ladder <= runs, (op, sorted(runs), sorted(ladder))
         assert cl._engine.prewarmer.errors == 0
         # Warm dispatches ran against scratch state: tenant data intact.
         assert bf.contains_all(np.arange(100, dtype=np.uint64)) == 100
