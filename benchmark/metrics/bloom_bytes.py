@@ -28,3 +28,15 @@ def window_bytes(run: dict, launched_contains: float) -> float:
     g = run["geometry"]
     return (run["ops"]["add_keys"] * add_bytes(g["k"], g["key_bytes"])
             + launched_contains * contains_bytes(g["k"], g["key_bytes"]))
+
+
+def roofline_share(run: dict, nbytes: float):
+    """Share of the HBM roofline, %: the window's bytes over what the
+    cell's chips could move while busy, chips x peak bytes/s x ``busy_s``
+    (the trace's busy seconds, a mean over the chips' device planes).
+    None without busy time or bytes."""
+    busy_s = run["trace"]["busy_s"]
+    if busy_s <= 0 or nbytes <= 0:
+        return None
+    return 100.0 * nbytes / (run["chips"] * run["peaks"]["hbm_bytes_per_s"]
+                             * busy_s)

@@ -1,8 +1,9 @@
 """Kernels: share of the HBM roofline.  Algorithmic bytes of the ops the
 window launched (``bloom_bytes``; near-cache and response-cache answers
-excluded) over (peak HBM bytes/s x device busy time from the trace).
-Every device op in these cells serves the Bloom path, so busy time is
-the kernels' time.  Random 4-byte gathers keep it far below 100."""
+excluded) over what the cell's chips could move in their busy time
+(``bloom_bytes.roofline_share``).  Every device op in these cells serves
+the Bloom path, so busy time is the kernels' time.  Random 4-byte gathers
+keep it far below 100."""
 
 from benchmark.metrics import _counters, bloom_bytes
 
@@ -13,14 +14,11 @@ def snapshot(sut):
 
 
 def read(before, after, run):
-    t = run.get("trace")
-    if not t or t["busy_s"] <= 0:
+    if not run.get("trace"):
         return None
     answered_on_host = (after["hits"] - before["hits"]
                         + after.get("resp_cache_hits", 0)
                         - before.get("resp_cache_hits", 0))
     launched = run["ops"]["contains_keys"] - answered_on_host
-    b = bloom_bytes.window_bytes(run, launched)
-    if b <= 0:
-        return None
-    return 100.0 * b / (run["peaks"]["hbm_bytes_per_s"] * t["busy_s"])
+    return bloom_bytes.roofline_share(run,
+                                      bloom_bytes.window_bytes(run, launched))

@@ -113,7 +113,9 @@ def compile_cache() -> str:
 
 
 class CompileCounter:
-    """XLA backend compiles (persistent-cache hits do not fire it)."""
+    """Programs made ready for the device: JAX times an XLA compile and a
+    load from the persistent cache as one backend compile, so a warm
+    run's set-up counts its cache loads here too."""
 
     def __init__(self):
         import jax
@@ -206,6 +208,7 @@ def run(args, gate=device_gate, control: bool = False) -> dict:
               "device_ops": {k: len(v) for k, v in raw["devices"].items()},
               "host_spans": len(raw["host"])})
         run_info = {"trace": red or None, "ops": drv.ops, "peaks": pk,
+                    "chips": w["chips"],
                     "geometry": {"k": drv.k, "m": drv.m,
                                  "key_bytes": cfg["key_bytes"]}}
         units = {m["name"]: m["unit"] for m in spec["per_layer"]}

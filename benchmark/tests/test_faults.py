@@ -1,15 +1,19 @@
 """A run with the timed path broken underneath must come out not
 correct, once for each fault a cell can have, and the control (the
 reference with one probe fewer, in the program's place) must fail the
-same comparison.  The exchange between chips is not among the faults:
-every cell runs on one chip."""
+same comparison.  The cells are the benchmark's own and the pending
+ones.  The exchange between chips is a fault only a cell on more than
+one chip can have: there each op is answered from the same slot of the
+next shard's results."""
 
 import numpy as np
 import pytest
 
-from benchmark.tests.rehearsal import run_tiny
+from benchmark.tests.rehearsal import run_tiny, spec, workloads
 
-CELLS = ["bulk-contains", "tenants-mixed", "tenants-resp", "bulk-add"]
+CELLS = workloads(spec())
+FAULTS = ["state_unchanged", "half_batch", "answer_altered"]
+ACROSS_CHIPS = ["exchange_shifted"]
 
 
 class _Mapped:
@@ -83,18 +87,31 @@ def _patch(monkeypatch, fault):
                            ("bloom_contains_encoded", has),
                            ("bloom_mixed_encoded", mixed)):
             monkeypatch.setattr(E, name, wrap(orig))
+    elif fault == "exchange_shifted":
+        from redisson_tpu.executor.sharded_executor import _Partition
+        from redisson_tpu.ops import bitops
+
+        def unpack_next_shard(self, packed):
+            un = bitops.unpack_bool_u32(
+                np.ascontiguousarray(packed).reshape(-1), self.S * self.Bp
+            ).reshape(self.S, self.Bp)
+            res = np.empty(self.B, bool)
+            res[self.order] = un[(self.sh_sorted + 1) % self.S, self.slot]
+            return res
+
+        monkeypatch.setattr(_Partition, "unpack_bools", unpack_next_shard)
 
 
-@pytest.mark.parametrize("workload", CELLS)
-@pytest.mark.parametrize("fault", ["state_unchanged", "half_batch",
-                                   "answer_altered"])
+@pytest.mark.parametrize("workload,fault", [
+    (w["name"], f) for w in CELLS
+    for f in FAULTS + (ACROSS_CHIPS if w["chips"] > 1 else [])])
 def test_fault_is_not_correct(monkeypatch, workload, fault):
     _patch(monkeypatch, fault)
     out = run_tiny(monkeypatch, workload, seed=11)
     assert not out["correct"], out["checks"]
 
 
-@pytest.mark.parametrize("workload", CELLS)
+@pytest.mark.parametrize("workload", [w["name"] for w in CELLS])
 def test_control_fails_and_program_passes(monkeypatch, workload):
     out = run_tiny(monkeypatch, workload, seed=12, control=True)
     assert out["correct"], out["checks"]

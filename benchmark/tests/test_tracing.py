@@ -56,6 +56,18 @@ def test_two_chips_average():
     assert abs(tracing.reduce(t)["busy_s"] - (0.035 + 0.1) / 2) < 1e-12
 
 
+def test_gaps_are_where_no_chip_is_busy():
+    # A second chip, busy [25, 55) ms and in the same [95, 100) op as the
+    # first: the union is [10, 60) + [95, 100), so one gap of 35 ms
+    # (60-95) and one of 10 ms (0-10), each once, not once a chip.
+    t = _trace()
+    t["devices"]["/device:TPU:1"] = [(25 * MS, 55 * MS, "y"),
+                                     (95 * MS, 110 * MS, "scatter")]
+    r = tracing.reduce(t)
+    assert [round(d, 9) for _, d in r["idle_gaps"]] == [0.035, 0.01]
+    assert abs(r["busy_s"] - (0.035 + 0.035) / 2) < 1e-12
+
+
 def test_no_window_or_no_device_reads_nothing():
     t = _trace()
     assert tracing.reduce({"devices": {}, "host": t["host"]}) == {}

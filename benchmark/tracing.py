@@ -100,8 +100,9 @@ def _open_span(host: list, t: float) -> str:
 
 
 def reduce(trace: dict) -> dict:
-    """Busy time, idle share, top device ops and named idle gaps over the
-    ``bench:window`` span, averaged over the device planes."""
+    """Busy time, idle share and top device ops over the ``bench:window``
+    span, averaged over the device planes, and the named idle gaps in
+    which no plane was busy."""
     win = [(s, e) for s, e, n in trace["host"] if n == WINDOW]
     if not win or not trace["devices"]:
         return {}
@@ -115,10 +116,14 @@ def reduce(trace: dict) -> dict:
             d = min(e, hi) - max(s, lo)
             if d > 0:
                 per_op[name] = per_op.get(name, 0) + d
-        edges = [lo] + [x for iv in merged for x in iv] + [hi]
-        for s, e in zip(edges[::2], edges[1::2]):
-            if e > s:
-                gaps.append((e - s, s, e))
+    # Idle gaps are the stretches in which no device of the cell ran an
+    # op: what the host did then held every chip back.
+    merged = union([op for ops in trace["devices"].values() for op in ops],
+                   lo, hi)
+    edges = [lo] + [x for iv in merged for x in iv] + [hi]
+    for s, e in zip(edges[::2], edges[1::2]):
+        if e > s:
+            gaps.append((e - s, s, e))
     n = len(trace["devices"])
     busy_ns = sum(busy) / n
     host = trace["host"]

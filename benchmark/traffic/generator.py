@@ -31,6 +31,9 @@ Parameters a mix may set (all are read here, none elsewhere):
   ``zipf_s``, ``warmup`` (the phases run before the window, each
   ``[seconds, multiple of rate]``: a ramp for the small launch shapes and
   short bursts for the large ones the coalescer merges).
+
+A mix's ``tiny`` object is not read here: it holds the overrides the CPU
+rehearsal (``tests/rehearsal.py``) applies, and no run on the chip uses it.
 """
 
 from __future__ import annotations
@@ -47,8 +50,8 @@ POOL_STRIDE = 1 << 24  # pool keys of filter f are indexes f * stride + j
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 
-def load(name: str) -> dict:
-    path = os.path.join(HERE, name + ".json")
+def load(name: str, directory: str = HERE) -> dict:
+    path = os.path.join(directory, name + ".json")
     with open(path) as f:
         return json.load(f)
 
