@@ -18,7 +18,8 @@ point                   where it fires
                         the bare prefix ``dispatch`` matches every method.
 ``fetch``               completion / D2H result fetch (LazyResult.result)
 ``h2d.staging``         pinned-staging H2D ship (``_put_staged``)
-``h2d.scatter``         sharded scatter staging (``_scatter_put``)
+``h2d.scatter``         sharded scatter staging (``_scatter_put``,
+                        the Bloom block ``_bloom_put``)
 ``prewarm``             AOT bucket pre-warm worker, before each warm call
 ``snapshot.save``       snapshot()/dump() I/O
 ``snapshot.load``       restore_snapshot()/restore() I/O

@@ -13,7 +13,10 @@ MasterSlaveEntry (SURVEY.md §3.2) — and ``shard_map`` with
 is B (round 2 replicated every batch to every shard: S×B), writes are
 shard-local, and results come back ``[S, Bp]`` bit-packed with no
 collective.  Collectives remain only for genuinely cross-shard ops
-(BITOP/PFMERGE, m-sharded bitmaps — parallel/mesh.py).
+(BITOP/PFMERGE, m-sharded bitmaps — parallel/mesh.py).  Every partitioned
+input is placed with the state's sharding, so each chip receives its own
+slice from the host; a Bloom launch packs all its columns into one
+``[S, W, Bp]`` block, so it is one transfer.
 
 Device-side hashing works in sharded mode too (``supports_device_hash``):
 raw codec lanes ride the partition and murmur runs in-kernel, so sharded
@@ -59,7 +62,7 @@ class _Partition:
     bitmaps."""
 
     __slots__ = ("S", "B", "Bp", "order", "sh_sorted", "slot", "lrows",
-                 "valid", "counts")
+                 "counts")
 
     @classmethod
     def from_rows(cls, S: int, rows, bucket_fn) -> "_Partition":
@@ -81,17 +84,6 @@ class _Partition:
         offsets = np.zeros(S, np.int64)
         np.cumsum(counts[:-1], out=offsets[1:])
         self.slot = np.arange(self.B, dtype=np.int64) - offsets[self.sh_sorted]
-        valid = np.zeros((S, self.Bp), bool)
-        valid[self.sh_sorted, self.slot] = True
-        self.valid = valid
-
-    def scatter(self, col, fill=0):
-        """[B] (or [B, L]) column -> [S, Bp] (or [S, Bp, L]) block."""
-        col = np.asarray(col)
-        shape = (self.S, self.Bp) + col.shape[1:]
-        out = np.full(shape, fill, col.dtype)
-        out[self.sh_sorted, self.slot] = col[self.order]
-        return out
 
     def unpack_bools(self, packed: np.ndarray) -> np.ndarray:
         """[S, Bp/32] packed results -> bool[B] in arrival order."""
@@ -135,6 +127,14 @@ class ShardedTpuCommandExecutor(TpuCommandExecutor):
                 f"count=N with JAX_PLATFORMS=cpu for a virtual mesh)"
             )
         self.S = self.ctx.n_shards
+        # A partitioned launch's inputs go committed to their owning
+        # chips, as the shard_map expects them: no hop through the
+        # default device and no re-slice at dispatch.  A mesh spanning
+        # other processes cannot be placed from this host alone, so
+        # there each input goes to the default device and the jit
+        # places it.
+        sh = self.ctx.state_sharding
+        self._put_sharding = sh if sh.is_fully_addressable else None
 
     # -- pool-state factory ------------------------------------------------
 
@@ -227,7 +227,48 @@ class ShardedTpuCommandExecutor(TpuCommandExecutor):
         view = slot.buf[:nwords].view(col.dtype)[:count].reshape(shape)
         view[...] = fill
         view[p.sh_sorted, p.slot] = col[p.order]
-        return _put_staged(slot, view)
+        return _put_staged(slot, view, self._put_sharding)
+
+    def _valid_put(self, p: _Partition):
+        """[S, Bp] bool: True at the slots that hold an op."""
+        return self._scatter_put(p, np.ones(p.B, bool))
+
+    def _place(self, arr):
+        """A small per-shard argument ([S, ...]) placed like the
+        partitioned columns."""
+        return jax.device_put(np.asarray(arr), self._put_sharding)
+
+    def _bloom_put(self, p: _Partition, m_arr, is_add, *payload):
+        """One partitioned Bloom launch as ONE [S, W, Bp] uint32 block
+        (layout: parallel/mesh.py, BLOOM_HEAD), packed in a pinned
+        staging buffer and shipped with one transfer.  ``payload``:
+        per-op [B] or [B, L] uint32 columns after the head rows."""
+        if _chaos.ENABLED:  # sharded scatter-staging fault point
+            _chaos.fire("h2d.scatter", data=payload[0])
+        W = pm.BLOOM_HEAD + sum(
+            1 if c.ndim == 1 else c.shape[1] for c in payload)
+        shape = (p.S, W, p.Bp)
+        nwords = p.S * W * p.Bp
+        # Depth 2, as for lane blocks: a keyed block can be tens of MB.
+        slot = self._staging.acquire(("bloom_block",) + shape, nwords,
+                                     depth=2)
+        view = slot.buf[:nwords].reshape(shape)
+        view[...] = 0
+        view[:, 1] = 1  # padded m must be nonzero (mod arithmetic)
+        s, j, o = p.sh_sorted, p.slot, p.order
+        view[s, 0, j] = p.lrows[o]
+        view[s, 1, j] = np.asarray(m_arr, np.uint32)[o]
+        view[s, 2, j] = np.where(np.asarray(is_add, bool)[o],
+                                 pm.BLOOM_ADD | pm.BLOOM_VALID, pm.BLOOM_VALID)
+        r = pm.BLOOM_HEAD
+        for c in payload:
+            if c.ndim == 1:
+                view[s, r, j] = c[o]
+                r += 1
+            else:
+                view[s, r:r + c.shape[1], j] = c[o]
+                r += c.shape[1]
+        return _put_staged(slot, view, self._put_sharding)
 
     # -- m-sharded bitset pools (config 3): rows at/above the word
     # threshold split their words contiguously across shards ---------------
@@ -267,15 +308,9 @@ class ShardedTpuCommandExecutor(TpuCommandExecutor):
             lambda: pm.psharded_bloom_mixed(self.ctx, k=k, words_per_row=wpr),
         )
         p = self._part(rows)
-        pool.state, packed = fn(
-            pool.state,
-            self._scatter_put(p, p.lrows),
-            self._scatter_put(p, np.asarray(h1m, np.uint32)),
-            self._scatter_put(p, np.asarray(h2m, np.uint32)),
-            self._scatter_put(p, np.asarray(m_arr, np.uint32), fill=1),
-            self._scatter_put(p, np.asarray(is_add, bool)),
-            jnp.asarray(p.valid),
-        )
+        pool.state, packed = fn(pool.state, self._bloom_put(
+            p, m_arr, is_add,
+            np.asarray(h1m, np.uint32), np.asarray(h2m, np.uint32)))
         return LazyResult(packed, transform=p.unpack_bools)
 
     def bloom_add(self, pool, rows, m_arr, k: int, h1m, h2m) -> LazyResult:
@@ -308,15 +343,8 @@ class ShardedTpuCommandExecutor(TpuCommandExecutor):
         lengths = np.asarray(lengths, np.uint32)
         if lengths.ndim == 0:
             lengths = np.full(len(rows), lengths, np.uint32)
-        pool.state, packed = fn(
-            pool.state,
-            self._scatter_put(p, p.lrows),
-            self._scatter_put(p, blocks_t),
-            self._scatter_put(p, lengths),
-            self._scatter_put(p, np.asarray(m_arr, np.uint32), fill=1),
-            self._scatter_put(p, np.asarray(is_add, bool)),
-            jnp.asarray(p.valid),
-        )
+        pool.state, packed = fn(pool.state, self._bloom_put(
+            p, m_arr, is_add, lengths, blocks_t))
         return LazyResult(packed, transform=p.unpack_bools)
 
     def bloom_add_fast_st(self, pool, row: int, m: int, k: int, h1m, h2m) -> LazyResult:
@@ -382,7 +410,7 @@ class ShardedTpuCommandExecutor(TpuCommandExecutor):
             self._scatter_put(p, np.asarray(c0, np.uint32)),
             self._scatter_put(p, np.asarray(c1, np.uint32)),
             self._scatter_put(p, np.asarray(c2, np.uint32)),
-            jnp.asarray(p.valid),
+            self._valid_put(p),
         )
         return packed, p
 
@@ -419,7 +447,7 @@ class ShardedTpuCommandExecutor(TpuCommandExecutor):
             self._scatter_put(p, p.lrows),
             self._scatter_put(p, blocks_t),
             self._scatter_put(p, lengths),
-            jnp.asarray(p.valid),
+            self._valid_put(p),
         )
         return LazyResult(
             packed, transform=lambda v: bool(np.any(p.unpack_bools(v)))
@@ -463,7 +491,7 @@ class ShardedTpuCommandExecutor(TpuCommandExecutor):
                 self._scatter_put(
                     p, np.asarray(opcodes, np.uint32), fill=bitset_ops.OP_GET
                 ),
-                jnp.asarray(p.valid),
+                self._valid_put(p),
             )
             return LazyResult(packed, transform=p.unpack_bools)
         wpr = pool.row_units
@@ -479,7 +507,7 @@ class ShardedTpuCommandExecutor(TpuCommandExecutor):
             self._scatter_put(
                 p, np.asarray(opcodes, np.uint32), fill=bitset_ops.OP_GET
             ),
-            jnp.asarray(p.valid),
+            self._valid_put(p),
         )
         return LazyResult(packed, transform=p.unpack_bools)
 
@@ -495,7 +523,7 @@ class ShardedTpuCommandExecutor(TpuCommandExecutor):
                 pool.state,
                 self._scatter_put(p, np.asarray(rows, np.int32)),
                 self._scatter_put(p, lidx),
-                jnp.asarray(p.valid),
+                self._valid_put(p),
             )
             return LazyResult(packed, transform=p.unpack_bools)
         wpr = pool.row_units
@@ -508,7 +536,7 @@ class ShardedTpuCommandExecutor(TpuCommandExecutor):
             pool.state,
             self._scatter_put(p, p.lrows),
             self._scatter_put(p, np.asarray(idx, np.uint32)),
-            jnp.asarray(p.valid),
+            self._valid_put(p),
         )
         return LazyResult(packed, transform=p.unpack_bools)
 
@@ -533,7 +561,7 @@ class ShardedTpuCommandExecutor(TpuCommandExecutor):
                 pool.state,
                 self._scatter_put(p, np.asarray(rows, np.int32)),
                 self._scatter_put(p, lidx),
-                jnp.asarray(p.valid),
+                self._valid_put(p),
             )
             return LazyResult(packed, transform=p.unpack_bools)
         wpr = pool.row_units
@@ -546,7 +574,7 @@ class ShardedTpuCommandExecutor(TpuCommandExecutor):
             pool.state,
             self._scatter_put(p, p.lrows),
             self._scatter_put(p, np.asarray(idx, np.uint32)),
-            jnp.asarray(p.valid),
+            self._valid_put(p),
         )
         return LazyResult(packed, transform=p.unpack_bools)
 
@@ -563,7 +591,7 @@ class ShardedTpuCommandExecutor(TpuCommandExecutor):
                     self.ctx, words_local=WL, value=value
                 ),
             )
-            pool.state = fn(pool.state, row, jnp.asarray(fb), jnp.asarray(tb))
+            pool.state = fn(pool.state, row, self._place(fb), self._place(tb))
             return LazyResult(None)
         wpr = pool.row_units
         fn = self._builder(
@@ -678,7 +706,7 @@ class ShardedTpuCommandExecutor(TpuCommandExecutor):
                 pool.state,
                 dst_row,
                 jnp.asarray(np.asarray(src_rows, np.int32)),
-                jnp.asarray(limit_local),
+                self._place(limit_local),
             )
             return LazyResult(None)
         wpr = pool.row_units
@@ -721,7 +749,7 @@ class ShardedTpuCommandExecutor(TpuCommandExecutor):
             self._scatter_put(p, np.asarray(h1w, np.uint32)),
             self._scatter_put(p, np.asarray(h2w, np.uint32)),
             self._scatter_put(p, np.asarray(weights, np.uint32)),
-            jnp.asarray(p.valid),
+            self._valid_put(p),
         )
         if mode == "est":
             est = fn(*args)
@@ -784,7 +812,7 @@ class ShardedTpuCommandExecutor(TpuCommandExecutor):
                 lambda: pm.msharded_row_write(self.ctx, words_local=WL),
             )
             pool.state = fn(
-                pool.state, row, jnp.asarray(np.asarray(data).reshape(self.S, WL))
+                pool.state, row, self._place(np.asarray(data).reshape(self.S, WL))
             )
             return
         u = pool.row_units

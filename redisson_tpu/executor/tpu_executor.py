@@ -206,16 +206,21 @@ def _host_may_alias() -> bool:
     return _HOST_MAY_ALIAS
 
 
-def _put_staged(slot: "_StagingSlot", view):
+def _put_staged(slot: "_StagingSlot", view, sharding=None):
     """Ship a packed staging view: direct (pinned, pending-tracked) on
-    accelerators; via a private copy on the zero-copy CPU backend."""
+    accelerators; via a private copy on the zero-copy CPU backend.
+    ``sharding`` places the view committed, each device's slice straight
+    from the host; None puts it on the default device, uncommitted."""
     if _chaos.ENABLED:  # staged-H2D fault point (ISSUE 3)
         _chaos.fire("h2d.staging", data=view)
-    # rtpu_h2d_bytes: the dispatching method records the sum once.
-    _spans.DISPATCH.h2d_bytes += view.nbytes
+    # rtpu_h2d_bytes / rtpu_h2d_puts: the dispatching method records the
+    # sums once.
+    d = _spans.DISPATCH
+    d.h2d_bytes += view.nbytes
+    d.h2d_puts += 1
     if _host_may_alias():
-        return jax.device_put(view.copy())
-    dev = jax.device_put(view)
+        return jax.device_put(view.copy(), sharding)
+    dev = jax.device_put(view, sharding)
     slot.pending = dev
     return dev
 
@@ -1762,9 +1767,10 @@ def _locked(fn):
                 return fn(self, *args, **kwargs)
             self._dispatch_recording = True
             # Compiles firing inside are labelled with this method
-            # (rtpu_compiles); the staging helpers add the H2D bytes.
+            # (rtpu_compiles); the staging helpers add the H2D bytes and
+            # transfers.
             d = _spans.DISPATCH
-            d.method, d.obs, d.h2d_bytes = name, obs, 0
+            d.method, d.obs, d.h2d_bytes, d.h2d_puts = name, obs, 0, 0
             t0 = time.monotonic()
             try:
                 # Host region in a jax.profiler capture, named by method.
@@ -1781,7 +1787,7 @@ def _locked(fn):
                 # longer report zero ops (ISSUE 1 satellite).
                 metrics.record_dispatch(nops=nops, enqueue_s=dur)
             if obs is not None:
-                obs.record_dispatch(name, nops, dur, d.h2d_bytes)
+                obs.record_dispatch(name, nops, dur, d.h2d_bytes, d.h2d_puts)
             return out
 
     return wrapper

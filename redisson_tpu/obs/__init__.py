@@ -106,6 +106,10 @@ class Observability:
             "rtpu_h2d_bytes",
             "bytes shipped host-to-device by the executor's staging "
             "helpers, by executor method", ("method",))
+        self.h2d_puts = r.counter(
+            "rtpu_h2d_puts",
+            "host-to-device transfers (device_put calls) the executor's "
+            "staging helpers make, by executor method", ("method",))
         self.compiles = r.counter(
             "rtpu_compiles",
             "XLA backend compiles, by the executor method dispatching "
@@ -436,12 +440,14 @@ class Observability:
         self.resp_latency.observe((cmd,), duration_s)
 
     def record_dispatch(self, method: str, nops: int, dur_s: float,
-                        h2d_bytes: int = 0) -> None:
+                        h2d_bytes: int = 0, h2d_puts: int = 0) -> None:
         self.dispatches.inc((method,))
         self.dispatch_ops.inc((method,), nops)
         self.dispatch_seconds.observe((method,), dur_s)
         if h2d_bytes:
             self.h2d_bytes.inc((method,), h2d_bytes)
+        if h2d_puts:
+            self.h2d_puts.inc((method,), h2d_puts)
 
     def encode_timer(self, kind: str) -> Timed:
         """``rtpu_encode_seconds{kind}`` + region ``rtpu:encode``."""

@@ -125,12 +125,14 @@ class WaitRegionLock:
 
 class _Dispatching(threading.local):
     """What the executor is dispatching on this thread: its method name,
-    the bundle recording it, and the H2D bytes its staging helpers have
-    shipped so far.  Read by the compile listener to label compiles."""
+    the bundle recording it, and the H2D bytes and ``device_put`` calls
+    its staging helpers have made so far.  Read by the compile listener
+    to label compiles."""
 
     method: Optional[str] = None
     obs = None
     h2d_bytes = 0
+    h2d_puts = 0
 
 
 DISPATCH = _Dispatching()

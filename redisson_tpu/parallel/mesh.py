@@ -248,7 +248,8 @@ def sharded_mbit_get(ctx: MeshContext, *, words_local: int):
 def _psharded(ctx: MeshContext, inner, n_op_args: int, *, out_state: bool, donate: bool = True,
               name: str = "psharded"):
     """shard_map wrapper for partitioned op batches: ``inner(local_state,
-    *op_cols)`` sees one shard's [Bp]-shaped columns and returns
+    *op_cols)`` sees one shard's slice of each op argument (a [Bp]
+    column, or a Bloom launch's [W, Bp] block) and returns
     (new_local, res[Bp-packed]) or just res.  ``name`` names the XLA
     module (``jit_<name>``): the calling builder's name."""
 
@@ -267,34 +268,55 @@ def _psharded(ctx: MeshContext, inner, n_op_args: int, *, out_state: bool, donat
     return _named_jit(fn, name, donate_argnums=(0,) if (out_state and donate) else ())
 
 
-def psharded_bloom_mixed(ctx: MeshContext, *, k: int, words_per_row: int):
-    """fn(state, lrows, h1m, h2m, m, is_add, valid) -> (new_state,
-    packed[S, Bp/32]); every column [S, Bp], rows already shard-local."""
+# A partitioned Bloom launch ships ONE uint32 block [S, W, Bp]: shard s's
+# [W, Bp] slice packs its ops' columns as the one-chip staging block does,
+# a word row per column, so the op axis stays the minor one.  Rows 0-2 are every op's local
+# row, m and flags (BLOOM_ADD | BLOOM_VALID; a padded slot is all zero
+# but m = 1), then the payload: h1m, h2m on the host-hash path, or the
+# key length and the trimmed key lanes on the device-hash path.
+BLOOM_HEAD = 3
+BLOOM_ADD, BLOOM_VALID = 1, 2
 
-    def inner(local, lrows, h1m, h2m, m_arr, is_add, valid):
+
+def _bloom_head(blk):
+    """[W, Bp] local block -> (local rows, m, is_add, valid, payload)."""
+    flags = blk[2]
+    return (lax.bitcast_convert_type(blk[0], jnp.int32), blk[1],
+            (flags & BLOOM_ADD) != 0, (flags & BLOOM_VALID) != 0,
+            blk[BLOOM_HEAD:])
+
+
+def psharded_bloom_mixed(ctx: MeshContext, *, k: int, words_per_row: int):
+    """fn(state, block[S, 5, Bp]) -> (new_state, packed[S, Bp/32]); the
+    block's payload rows are h1m, h2m; rows already shard-local."""
+
+    def inner(local, blk):
+        lrows, m_arr, is_add, valid, (h1m, h2m) = _bloom_head(blk)
         new_local, res = bloom.bloom_mixed(
             local, lrows, h1m, h2m, is_add,
             m=m_arr, k=k, words_per_row=words_per_row, valid=valid,
         )
         return new_local[None], bitops.pack_bool_u32(res)[None]
 
-    return _psharded(ctx, inner, 6, out_state=True, name="psharded_bloom_mixed")
+    return _psharded(ctx, inner, 1, out_state=True, name="psharded_bloom_mixed")
 
 
 def psharded_bloom_mixed_keys(ctx: MeshContext, *, k: int, words_per_row: int, target_lanes: int):
-    """Device-hash variant: raw codec lanes [S, Bp, L] hash in-kernel (the
+    """Device-hash variant: the block's payload rows are the key length
+    and the trimmed codec lanes ([S, 4 + Lt, Bp]), hashed in-kernel (the
     round-2 sharded mode shipped 16-byte host hashes — the fast path now
     works sharded too)."""
     from redisson_tpu.ops import fastpath
 
-    def inner(local, lrows, blocks, lengths, m_arr, is_add, valid):
+    def inner(local, blk):
+        lrows, m_arr, is_add, valid, payload = _bloom_head(blk)
         new_local, res = fastpath.bloom_mixed_keys(
-            local, lrows, blocks, lengths, m_arr, is_add, valid,
+            local, lrows, payload[1:].T, payload[0], m_arr, is_add, valid,
             k=k, words_per_row=words_per_row, target_lanes=target_lanes,
         )
         return new_local[None], bitops.pack_bool_u32(res)[None]
 
-    return _psharded(ctx, inner, 6, out_state=True, name="psharded_bloom_mixed_keys")
+    return _psharded(ctx, inner, 1, out_state=True, name="psharded_bloom_mixed_keys")
 
 
 def psharded_bitset_mixed(ctx: MeshContext, *, words_per_row: int):

@@ -261,13 +261,9 @@ def test_mesh_tenant_sharded_bloom(mesh4):
     state = _sharded(mesh4, (S, 16 * W + 1), jnp.uint32)
     fn = pm.psharded_bloom_mixed_keys(mesh4, k=K, words_per_row=W,
                                       target_lanes=4)
-    cols = [_sharded(mesh4, (S, Bp), jnp.int32),
-            _sharded(mesh4, (S, Bp, 2), jnp.uint32),
-            _sharded(mesh4, (S, Bp), jnp.uint32),
-            _sharded(mesh4, (S, Bp), jnp.uint32),
-            _sharded(mesh4, (S, Bp), jnp.bool_),
-            _sharded(mesh4, (S, Bp), jnp.bool_)]
-    fn.lower(state, *cols).compile()
+    # One packed block: the head rows, the key length, 2 key lanes.
+    block = _sharded(mesh4, (S, pm.BLOOM_HEAD + 1 + 2, Bp), jnp.uint32)
+    fn.lower(state, block).compile()
 
 
 @pytest.mark.parametrize("op", ["pfmerge", "bitop_or"])
