@@ -254,8 +254,7 @@ def bench_config4_mixed(make_client):
     to UN-collected queues); min_bucket=4096 bounds the set of padded
     shapes so the pre-warm ladder covers every compile.
     """
-    client = make_client(coalesce=True, exact_add_semantics=True,
-                         batch_window_us=200, max_batch=1 << 18,
+    client = make_client(coalesce=True, batch_window_us=200, max_batch=1 << 18,
                          min_bucket=4096, max_inflight=16, min_inflight=4,
                          max_queued_ops=1 << 19, prewarm=True)
     n_tenants = 1000
@@ -2788,7 +2787,7 @@ def bench_full_geometry(make_client):
     — 10M-cardinality HLL stream, 100M-event CMS top-K): run once per
     round outside the driver's default bench, results appended to
     BASELINE.md.  Wall-clock heavy by design."""
-    client = make_client(exact_add_semantics=False, coalesce=False)
+    client = make_client(coalesce=False)
     out = {}
 
     # Config 2 at 10M cardinality.
@@ -3105,7 +3104,7 @@ def main():
     # (that serves the mixed multi-tenant QPS config below).
     link = measure_link_calibration()
     link["device_kernel_contains_ops_per_sec"] = measure_device_kernel()
-    client = make_client(exact_add_semantics=False, coalesce=False)
+    client = make_client(coalesce=False)
     (
         contains_ops,
         fpp,

@@ -13,7 +13,8 @@ point                   where it fires
 ======================  ====================================================
 ``dispatch.<method>``   inside the executor dispatch wrapper (``_locked``
                         in tpu_executor.py — shared with the sharded
-                        executor), per method: ``dispatch.bloom_mixed``,
+                        executor), per method:
+                        ``dispatch.bloom_mixed_keys``,
                         ``dispatch.read_row``, ...  A rule installed under
                         the bare prefix ``dispatch`` matches every method.
 ``fetch``               completion / D2H result fetch (LazyResult.result)

@@ -170,11 +170,6 @@ class TpuSketchConfig:
         self.mailbox_collect = True
         # Tenancy.
         self.initial_tenants_per_class = 8  # initial rows per size-class pool
-        # Exact intra-batch sequential semantics for bloom add (sort-based
-        # kernel).  False selects the fast single-tenant add whose
-        # newly-added flags are computed vs pre-batch state (bit-level
-        # results identical; see ops/fastpath.py).
-        self.exact_add_semantics = True
         self.max_bloom_bits = 1 << 31
         # Sharding: 1 → single-device executor; S > 1 → the cluster-mode
         # analog (executor/sharded_executor.py): tenant row r lives on

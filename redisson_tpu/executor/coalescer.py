@@ -63,7 +63,7 @@ from redisson_tpu.executor.failures import (
 
 def _op_label(key) -> str:
     """Human label for a segment key (keys are tuples whose first element
-    names the op path, e.g. ("bloom_mix", id(pool), k))."""
+    names the op path, e.g. ("bloom_mixk", id(pool), k, L))."""
     if isinstance(key, tuple) and key and isinstance(key[0], str):
         return key[0]
     return "op"

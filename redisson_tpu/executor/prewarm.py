@@ -103,17 +103,6 @@ def _raw(executor, name: str) -> Callable:
 # traffic exactly (dtypes and shapes are what the jit cache keys on).
 
 
-def warm_bloom_mixed(k: int) -> Callable:
-    def fn(ex, wpool, B):
-        rows = np.arange(B, dtype=np.int64) % max(1, wpool.capacity)
-        h = np.zeros(B, np.uint32)
-        _raw(ex, "bloom_mixed")(
-            ex, wpool, rows.astype(np.int32), np.ones(B, np.uint32), k,
-            h, h, np.zeros(B, bool),
-        )
-    return fn
-
-
 def warm_bloom_mixed_keys(k: int, L: int, Lt: int) -> Callable:
     def fn(ex, wpool, B):
         blocks = np.zeros((B, L), np.uint32)
@@ -131,7 +120,7 @@ def warm_bloom_mixed_keys_runs(k: int, L: int, Lt: int, const_len: bool) -> Call
     gather (bloom_contains_keys_runs) and the scan that segments holding
     an add take (bloom_mixed_keys_runs, which scans whatever its flags)."""
     def fn(ex, wpool, B):
-        if not getattr(ex, "supports_runs_metadata", False):
+        if not ex.supports_runs_metadata:
             return  # rebound to a sharded successor: no runs kernel
         blocks = np.zeros((B, L), np.uint32)
         blocks[:, :Lt] = 1
@@ -163,7 +152,7 @@ def warm_bitset_mixed_runs() -> Callable:
     def fn(ex, wpool, B):
         from redisson_tpu.ops import bitset as bitset_ops
 
-        if not getattr(ex, "supports_runs_metadata", False):
+        if not ex.supports_runs_metadata:
             return  # rebound to a sharded successor: no runs kernel
         _raw(ex, "bitset_mixed_runs")(
             ex, wpool, np.zeros(B, np.uint32),

@@ -18,9 +18,9 @@ input is placed with the state's sharding, so each chip receives its own
 slice from the host; a Bloom launch packs all its columns into one
 ``[S, W, Bp]`` block, so it is one transfer.
 
-Device-side hashing works in sharded mode too (``supports_device_hash``):
-raw codec lanes ride the partition and murmur runs in-kernel, so sharded
-traffic ships key bytes, not 16-byte host hashes.
+Every Bloom launch hashes on the device: raw codec lanes ride the
+partition and murmur runs in-kernel, so sharded traffic ships key bytes,
+not 16-byte host hashes.
 
 Pool state: ``[S, local_len]`` arrays block-sharded along axis 0
 (NamedSharding over a ``jax.sharding.Mesh``); each shard's local block is a
@@ -102,10 +102,6 @@ class _Partition:
 
 
 class ShardedTpuCommandExecutor(TpuCommandExecutor):
-    # Raw codec lanes partition like any other column; murmur runs
-    # in-kernel on the owning shard (was False in round 2 — sharded mode
-    # silently dropped the device-hash fast path).
-    supports_device_hash = True
     # The Pallas sequential kernel operates on a single-device VMEM table;
     # sharded CMS traffic uses the partitioned XLA path instead.
     supports_pallas_cms = False
@@ -298,33 +294,8 @@ class ShardedTpuCommandExecutor(TpuCommandExecutor):
         lidx = (idx - shard * (WL * 32)).astype(np.uint32)
         return p, lidx
 
-    # -- bloom (all single-bit traffic routes through the partitioned
+    # -- bloom (every Bloom op routes through the partitioned keyed
     # mixed kernel: adds are is_add=True ops, contains is_add=False) -------
-
-    def _bloom_mixed_part(self, pool, rows, m_arr, k: int, h1m, h2m, is_add):
-        wpr = pool.row_units
-        fn = self._builder(
-            ("psh_bloom_mixed", wpr, k),
-            lambda: pm.psharded_bloom_mixed(self.ctx, k=k, words_per_row=wpr),
-        )
-        p = self._part(rows)
-        pool.state, packed = fn(pool.state, self._bloom_put(
-            p, m_arr, is_add,
-            np.asarray(h1m, np.uint32), np.asarray(h2m, np.uint32)))
-        return LazyResult(packed, transform=p.unpack_bools)
-
-    def bloom_add(self, pool, rows, m_arr, k: int, h1m, h2m) -> LazyResult:
-        return self._bloom_mixed_part(
-            pool, rows, m_arr, k, h1m, h2m, np.ones(len(h1m), bool)
-        )
-
-    def bloom_contains(self, pool, rows, m_arr, k: int, h1m, h2m) -> LazyResult:
-        return self._bloom_mixed_part(
-            pool, rows, m_arr, k, h1m, h2m, np.zeros(len(h1m), bool)
-        )
-
-    def bloom_mixed(self, pool, rows, m_arr, k: int, h1m, h2m, is_add) -> LazyResult:
-        return self._bloom_mixed_part(pool, rows, m_arr, k, h1m, h2m, is_add)
 
     def bloom_mixed_keys(self, pool, rows, m_arr, k: int, blocks, lengths, is_add) -> LazyResult:
         """Partitioned device-hash path: key lanes ship to the owning shard
@@ -346,32 +317,6 @@ class ShardedTpuCommandExecutor(TpuCommandExecutor):
         pool.state, packed = fn(pool.state, self._bloom_put(
             p, m_arr, is_add, lengths, blocks_t))
         return LazyResult(packed, transform=p.unpack_bools)
-
-    def bloom_add_fast_st(self, pool, row: int, m: int, k: int, h1m, h2m) -> LazyResult:
-        # Sharded mode has no single-tenant bit-delta fast path (the row
-        # lives on one shard anyway); route through the exact multi-tenant
-        # kernel.  Duplicate keys in one batch get exact sequential flags —
-        # a strict refinement of the fast path's pre-batch semantics.
-        rows = np.full(h1m.shape[0], row, np.int32)
-        m_arr = np.full(h1m.shape[0], m, np.uint32)
-        return self.bloom_add(pool, rows, m_arr, k, h1m, h2m)
-
-    def bloom_contains_st(self, pool, row: int, m: int, k: int, h1m, h2m) -> LazyResult:
-        rows = np.full(h1m.shape[0], row, np.int32)
-        m_arr = np.full(h1m.shape[0], m, np.uint32)
-        return self.bloom_contains(pool, rows, m_arr, k, h1m, h2m)
-
-    def bloom_add_keys_st(self, pool, row: int, m: int, k: int, blocks, lengths) -> LazyResult:
-        B = blocks.shape[0]
-        return self.bloom_mixed_keys(
-            pool,
-            np.full(B, row, np.int32),
-            np.full(B, m, np.uint32),
-            k,
-            blocks,
-            lengths,
-            np.ones(B, bool),
-        )
 
     def bloom_contains_keys_st(self, pool, row: int, m: int, k: int, blocks, lengths) -> LazyResult:
         B = blocks.shape[0]

@@ -37,7 +37,6 @@ from redisson_tpu.ops import fastpath
 from redisson_tpu.ops import golden
 from redisson_tpu.ops import hll as hll_ops
 from redisson_tpu.obs import spans as _spans
-from redisson_tpu.tenancy import SizeClassPool
 
 
 # rtpulint: disable=RT006 keyed by Mesh topology (a handful per process, meshes hash by content), not by object/tenant name — bounded by construction
@@ -357,9 +356,6 @@ class TpuCommandExecutor:
     racing on the same state would hand XLA an already-consumed buffer.
     Device execution itself stays async — the lock only covers enqueue."""
 
-    # Single-device layout supports the *_keys_st device-hash kernels; the
-    # sharded executor routes encoded batches through the host hash instead.
-    supports_device_hash = True
     # Observability wiring (engine sets these): ``metrics`` is the legacy
     # Metrics aggregate, set ONLY when no coalescer fronts this executor
     # (the coalescer records the same ops_total/batches_total itself —
@@ -646,111 +642,43 @@ class TpuCommandExecutor:
 
     # -- bloom -------------------------------------------------------------
 
-    def bloom_add(self, pool: SizeClassPool, rows, m_arr, k: int, h1m, h2m) -> LazyResult:
-        B = h1m.shape[0]
-        Bp = self._bucket(B)
-        wpr = pool.row_units
-        key = ("bloom_add", wpr, pool.state.shape[0], Bp, k)
-
-        def build():
-            def f(state, rows, h1m, h2m, m_arr, valid):
-                new, newly = bloom_ops.bloom_add(
-                    state, rows, h1m, h2m, m=m_arr, k=k, words_per_row=wpr, valid=valid
-                )
-                return new, bitops.pack_bool_u32(newly)
-            return f
-
-        fn = self._jit(key, build, donate=True)
-        # Padded m must be nonzero (mod arithmetic); 1 is harmless.
-        (rows_p, h1_p, h2_p), valid = self._pad_ops(Bp, rows, h1m, h2m)
-        m_p = self._staged_put(m_arr, Bp, fill=1)
-        pool.state, newly = fn(pool.state, rows_p, h1_p, h2_p, m_p, valid)
-        return LazyResult(newly, transform=lambda v: bitops.unpack_bool_u32(v, B))
-
-    def bloom_contains(self, pool, rows, m_arr, k: int, h1m, h2m) -> LazyResult:
-        B = h1m.shape[0]
-        Bp = self._bucket(B)
-        wpr = pool.row_units
-        key = ("bloom_contains", wpr, pool.state.shape[0], Bp, k)
-
-        def build():
-            def f(state, rows, h1m, h2m, m_arr):
-                return bitops.pack_bool_u32(bloom_ops.bloom_contains(
-                    state, rows, h1m, h2m, m=m_arr, k=k, words_per_row=wpr
-                ))
-            return f
-
-        fn = self._jit(key, build, donate=False)
-        (rows_p, h1_p, h2_p), _ = self._pad_ops(Bp, rows, h1m, h2m)
-        m_p = self._staged_put(m_arr, Bp, fill=1)
-        out = fn(pool.state, rows_p, h1_p, h2_p, m_p)
-        return LazyResult(out, transform=lambda v: bitops.unpack_bool_u32(v, B))
-
-    def bloom_mixed(self, pool, rows, m_arr, k: int, h1m, h2m, is_add) -> LazyResult:
-        """Combined add+contains batch (ops/bloom.bloom_mixed): the
-        coalescer's hot path — mixed multi-tenant traffic stays in ONE
-        segment per (pool, k).
-
-        Fused H2D: the whole batch (rows, m, h1, h2, bit-packed is_add,
-        real-op count in word 0) ships as ONE contiguous staging block →
-        one device_put per flush instead of 6 transfers; the jit slices
-        columns back out (free — XLA fuses the slices into the kernel)
-        and rebuilds valid as ``iota < n``."""
-        B = h1m.shape[0]
-        Bp = self._bucket(B)
-        wpr = pool.row_units
-        Wb = Bp >> 5
-        key = ("bloom_mixed", wpr, pool.state.shape[0], Bp, k)
-
-        def build():
-            def f(state, packed):
-                n = jax.lax.bitcast_convert_type(packed[0], jnp.int32)
-                o = 1
-                rows = jax.lax.bitcast_convert_type(
-                    packed[o : o + Bp], jnp.int32)
-                o += Bp
-                m_arr = packed[o : o + Bp]
-                o += Bp
-                h1m = packed[o : o + Bp]
-                o += Bp
-                h2m = packed[o : o + Bp]
-                o += Bp
-                is_add = bitops.unpack_bool_u32_dev(packed[o : o + Wb], Bp)
-                valid = jnp.arange(Bp, dtype=jnp.int32) < n
-                new, res = bloom_ops.bloom_mixed(
-                    state, rows, h1m, h2m, is_add,
-                    m=m_arr, k=k, words_per_row=wpr, valid=valid,
-                )
-                return new, bitops.pack_bool_u32(res)
-            return f
-
-        fn = self._jit(key, build, donate=True)
-        total = 1 + 4 * Bp + Wb
-        slot = self._staging.acquire(("bloom_mixed", Bp), total)
-        buf = slot.buf
-        buf[0] = B
-        o = _fill_words(buf, 1, Bp, np.asarray(rows, np.int32), np.int32)
-        # Padded m must be nonzero (mod arithmetic); 1 is harmless.
-        o = _fill_words(buf, o, Bp, np.asarray(m_arr, np.uint32), np.uint32, 1)
-        o = _fill_words(buf, o, Bp, np.asarray(h1m, np.uint32), np.uint32)
-        o = _fill_words(buf, o, Bp, np.asarray(h2m, np.uint32), np.uint32)
-        _fill_bits(buf, o, Bp, is_add)
-        pool.state, res = fn(pool.state, self._ship(slot, total))
-        return LazyResult(res, transform=lambda v: bitops.unpack_bool_u32(v, B))
-
     def bloom_mixed_keys(self, pool, rows, m_arr, k: int, blocks, lengths, is_add) -> LazyResult:
-        """Combined add+contains from raw codec lanes — device-side murmur
-        + 64-bit mod (ops/fastpath.py), multi-tenant rows/m as arrays.
-        Fused H2D: one packed staging block per flush (see bloom_mixed)."""
+        """Combined add+contains from raw codec lanes with exact
+        sequential semantics — device-side murmur + 64-bit mod
+        (ops/fastpath.py), multi-tenant rows/m as arrays.
+
+        Fused H2D: the whole batch (rows, lengths, m, bit-packed is_add,
+        key lanes, real-op count in word 0) ships as ONE contiguous
+        staging block; the jit slices the columns back out (XLA fuses the
+        slices into the kernel) and rebuilds valid as ``iota < n``.
+
+        A batch over ``_SCAN_CHUNK`` ops (a direct multi-million-key add)
+        lax.scans ``_SCAN_CHUNK``-op chunks in order, each seeing the
+        earlier chunks' writes, so answers stay exact while the device
+        hash's intermediates stay bounded."""
         B = blocks.shape[0]
         Bp = self._bucket(B)
+        if Bp > _SCAN_CHUNK and Bp % _SCAN_CHUNK:
+            # Round huge buckets UP to a chunk multiple (a custom
+            # min_bucket need not be a power of two): un-chunked
+            # multi-million-op device-hash kernels fail compile on HBM.
+            Bp = ((Bp // _SCAN_CHUNK) + 1) * _SCAN_CHUNK
         blocks, L = self._trim_lanes(blocks)
         Lt = blocks.shape[1]
         wpr = pool.row_units
         Wb = Bp >> 5
-        key = ("bloom_mixed_keys", wpr, pool.state.shape[0], Bp, k, L, Lt)
+        chunk = min(Bp, _SCAN_CHUNK)
+        key = ("bloom_mixed_keys", wpr, pool.state.shape[0], Bp, k, L, Lt,
+               chunk)
 
         def build():
+            def run(state, rows, lengths, m_arr, is_add, blocks, valid):
+                new, res = fastpath.bloom_mixed_keys(
+                    state, rows, blocks, lengths, m_arr, is_add, valid,
+                    k=k, words_per_row=wpr, target_lanes=L,
+                )
+                return new, bitops.pack_bool_u32(res)
+
             def f(state, packed):
                 n = jax.lax.bitcast_convert_type(packed[0], jnp.int32)
                 o = 1
@@ -765,11 +693,15 @@ class TpuCommandExecutor:
                 o += Wb
                 blocks = packed[o : o + Bp * Lt].reshape(Bp, Lt)
                 valid = jnp.arange(Bp, dtype=jnp.int32) < n
-                new, res = fastpath.bloom_mixed_keys(
-                    state, rows, blocks, lengths, m_arr, is_add, valid,
-                    k=k, words_per_row=wpr, target_lanes=L,
+                cols = (rows, lengths, m_arr, is_add, blocks, valid)
+                if chunk == Bp:
+                    return run(state, *cols)
+                new, outs = jax.lax.scan(
+                    lambda st, c: run(st, *c), state,
+                    tuple(c.reshape((Bp // chunk, chunk) + c.shape[1:])
+                          for c in cols),
                 )
-                return new, bitops.pack_bool_u32(res)
+                return new, outs.reshape(-1)
             return f
 
         fn = self._jit(key, build, donate=True)
@@ -778,8 +710,9 @@ class TpuCommandExecutor:
         buf = slot.buf
         buf[0] = B
         o = _fill_words(buf, 1, Bp, np.asarray(rows, np.int32), np.int32)
-        o = _fill_words(
-            buf, o, Bp, np.asarray(lengths, np.uint32), np.uint32)
+        o = _fill_words(buf, o, Bp, np.broadcast_to(
+            np.asarray(lengths, np.uint32), (B,)), np.uint32)
+        # Padded m must be nonzero (mod arithmetic); 1 is harmless.
         o = _fill_words(
             buf, o, Bp, np.asarray(m_arr, np.uint32), np.uint32, 1)
         o = _fill_bits(buf, o, Bp, is_add)
@@ -957,7 +890,7 @@ class TpuCommandExecutor:
     def bitset_mixed(self, pool, rows, idx, opcodes) -> LazyResult:
         """Unified set/clear/flip/get batch (ops/bitset.bitset_mixed) —
         one segment per bitset pool under interleaved opcodes.  Fused
-        H2D: one packed staging block per flush (see bloom_mixed)."""
+        H2D: one packed staging block per flush (see bloom_mixed_keys)."""
         B = idx.shape[0]
         Bp = self._bucket(B)
         wpr = pool.row_units
@@ -992,126 +925,6 @@ class TpuCommandExecutor:
                     bitset_ops.OP_GET)
         pool.state, obs = fn(pool.state, self._ship(slot, total))
         return LazyResult(obs, transform=lambda v: bitops.unpack_bool_u32(v, B))
-
-    def bloom_add_fast_st(self, pool, row: int, m: int, k: int, h1m, h2m) -> LazyResult:
-        """Single-tenant fast add (snapshot newly semantics, see
-        ops/fastpath.py).  row/m travel as scalars, not arrays."""
-        B = h1m.shape[0]
-        Bp = self._bucket(B)
-        wpr = pool.row_units
-        key = ("bloom_add_fast", wpr, pool.state.shape[0], Bp, k)
-
-        def build():
-            def f(state, row, h1m, h2m, m, valid):
-                new, newly = fastpath.bloom_add_fast_st(
-                    state, row, h1m, h2m, m, valid, k=k, words_per_row=wpr
-                )
-                return new, bitops.pack_bool_u32(newly)
-            return f
-
-        fn = self._jit(key, build, donate=True)
-        (h1_p, h2_p), valid = self._pad_ops(Bp, h1m, h2m)
-        pool.state, newly = fn(
-            pool.state, np.int32(row), h1_p, h2_p, np.uint32(m), valid
-        )
-        return LazyResult(newly, transform=lambda v: bitops.unpack_bool_u32(v, B))
-
-    def bloom_contains_st(self, pool, row: int, m: int, k: int, h1m, h2m) -> LazyResult:
-        """Single-tenant contains; bit-exact, fewer transfers."""
-        B = h1m.shape[0]
-        Bp = self._bucket(B)
-        wpr = pool.row_units
-        key = ("bloom_contains_st", wpr, pool.state.shape[0], Bp, k)
-
-        def build():
-            def f(state, row, h1m, h2m, m):
-                return bitops.pack_bool_u32(fastpath.bloom_contains_st(
-                    state, row, h1m, h2m, m, k=k, words_per_row=wpr
-                ))
-            return f
-
-        fn = self._jit(key, build, donate=False)
-        (h1_p, h2_p), _ = self._pad_ops(Bp, h1m, h2m)
-        out = fn(pool.state, np.int32(row), h1_p, h2_p, np.uint32(m))
-        return LazyResult(out, transform=lambda v: bitops.unpack_bool_u32(v, B))
-
-    def bloom_add_keys_st(self, pool, row: int, m: int, k: int, blocks, lengths) -> LazyResult:
-        """Single-tenant add from raw codec lanes — murmur + 64-bit mod run
-        in-kernel (ops/fastpath.py device-hash path), so the host ships only
-        the key bytes.
-
-        ``newly`` semantics on this fast (non-exact) path are
-        snapshot-vs-pre-batch for batches within one scan chunk; across
-        chunks of a huge batch they become chunk-sequential (a duplicate
-        in a LATER chunk observes the earlier chunk's bits and reports
-        False) — strictly MORE accurate, and within the fast path's
-        documented approximation.  ``exact_add_semantics`` remains the
-        mode for exact per-op sequential results."""
-        B = blocks.shape[0]
-        Bp = self._bucket(B)
-        blocks, L = self._trim_lanes(blocks)
-        Lt = blocks.shape[1]
-        wpr = pool.row_units
-        const_len = bool(B == 0 or np.all(lengths == lengths[0]))
-        if Bp > _SCAN_CHUNK and Bp % _SCAN_CHUNK:
-            # Round huge buckets UP to a chunk multiple (a custom
-            # min_bucket need not be a power of two): the scan guarantee
-            # must hold for EVERY huge launch — un-chunked multi-million
-            # -op device-hash kernels fail compile on HBM.
-            Bp = ((Bp // _SCAN_CHUNK) + 1) * _SCAN_CHUNK
-        key = ("bloom_add_keys", wpr, pool.state.shape[0], Bp, k, L, Lt, const_len)
-
-        def build():
-            def one(state, row, blocks, lengths, m, valid):
-                new, newly = fastpath.bloom_add_keys_st(
-                    state, row, blocks, lengths, m, valid,
-                    k=k, words_per_row=wpr, target_lanes=L,
-                )
-                return new, bitops.pack_bool_u32(newly)
-
-            if Bp <= _SCAN_CHUNK:
-                return one
-
-            nc = Bp // _SCAN_CHUNK
-
-            def f(state, row, blocks, lengths, m, valid):
-                blocks_c = blocks.reshape(nc, _SCAN_CHUNK, blocks.shape[1])
-                valid_c = valid.reshape(nc, _SCAN_CHUNK)
-                if const_len:
-                    def body(st, xs):
-                        return one(st, row, xs[0], lengths, m, xs[1])
-
-                    new_state, outs = jax.lax.scan(
-                        body, state, (blocks_c, valid_c)
-                    )
-                else:
-                    def body(st, xs):
-                        return one(st, row, xs[0], xs[2], m, xs[1])
-
-                    new_state, outs = jax.lax.scan(
-                        body, state,
-                        (blocks_c, valid_c,
-                         lengths.reshape(nc, _SCAN_CHUNK)),
-                    )
-                return new_state, outs.reshape(-1)
-
-            return f
-
-        fn = self._jit(key, build, donate=True)
-        len_arg = (
-            np.uint32(lengths[0] if B else 0)
-            if const_len
-            else self._staged_put(lengths, Bp, dtype=np.uint32)
-        )
-        pool.state, newly = fn(
-            pool.state,
-            np.int32(row),
-            self._staged_blocks(blocks, Bp),
-            len_arg,
-            np.uint32(m),
-            self._staged_valid(B, Bp),
-        )
-        return LazyResult(newly, transform=lambda v: bitops.unpack_bool_u32(v, B))
 
     def bloom_contains_keys_st(self, pool, row: int, m: int, k: int, blocks, lengths) -> LazyResult:
         """Single-tenant contains from raw codec lanes (device-side hash)."""
@@ -1655,9 +1468,10 @@ class TpuCommandExecutor:
 def _nops_of(name: str, args) -> int:
     """Best-effort op count of a dispatch call: the longest sized
     operand after the pool (the per-op column — rows for multi-tenant
-    methods, hash/key columns for the *_st fast paths whose args[1] is
-    a scalar row).  str/bytes args (opcode names) never count, and
-    write_row's data payload is a row image, not an op batch."""
+    methods, the key block for single-row methods such as
+    bloom_contains_keys_st whose args[1] is a scalar row).  str/bytes
+    args (opcode names) never count, and write_row's data payload is a
+    row image, not an op batch."""
     if name == "write_row":
         return 1
     best = 1
@@ -1694,7 +1508,7 @@ def _locked(fn):
     name = fn.__name__
     annotation = "rtpu:" + name  # host-region label (one str, not per call)
     # Chaos fault point, one interned string per method (zero per-call
-    # allocation): rules can target one method ("dispatch.bloom_mixed")
+    # allocation): rules can target one method ("dispatch.bloom_mixed_keys")
     # or the whole boundary ("dispatch").
     fault_point = "dispatch." + name
     sheddable = name not in _DEADLINE_EXEMPT
@@ -1759,11 +1573,12 @@ def _locked(fn):
             if obs is None and metrics is None:
                 return fn(self, *args, **kwargs)
             if getattr(self, "_dispatch_recording", False):
-                # Nested wrapped call (an *_st fast path delegating to
-                # bloom_add, zero_row -> write_row, ...): the OUTERMOST
-                # wrapper records; recording here too would double-count
-                # launches and ops.  Safe as a plain attribute — we hold
-                # the reentrant dispatch lock on this thread.
+                # Nested wrapped call (a sharded *_st method delegating
+                # to bloom_mixed_keys, zero_row -> write_row, ...): the
+                # OUTERMOST wrapper records; recording here too would
+                # double-count launches and ops.  Safe as a plain
+                # attribute — we hold the reentrant dispatch lock on
+                # this thread.
                 return fn(self, *args, **kwargs)
             self._dispatch_recording = True
             # Compiles firing inside are labelled with this method
@@ -1797,17 +1612,11 @@ def _locked(fn):
 # threads would otherwise race, see class docstring).  Shared with the
 # sharded executor so the two wrap lists cannot drift.
 DISPATCH_METHODS = (
-    "bloom_add",
-    "bloom_contains",
-    "bloom_mixed",
     "bloom_mixed_keys",
     "bloom_mixed_keys_runs",
     "bloom_contains_keys_runs",
     "bitset_mixed",
     "bitset_mixed_runs",
-    "bloom_add_fast_st",
-    "bloom_contains_st",
-    "bloom_add_keys_st",
     "bloom_contains_keys_st",
     "hll_add_keys_single",
     "bloom_count",

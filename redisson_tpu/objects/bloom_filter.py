@@ -130,23 +130,15 @@ class BloomFilter(RObject):
         one fetch): membership is read-only, so splitting the result
         back per batch is exact, and the whole group costs three link
         transfers however many batches ride it.  The single-launch form
-        requires a route to the scan-chunked ``*_keys_st`` kernels (or
-        the host engine) — the coalesced/replicated ``bloom_mixed_keys``
-        path has no scan chunking, and a multi-million-op un-chunked
-        device-hash launch fails compile on HBM — so those engines keep
-        the per-batch pipelined form."""
+        needs the direct engine's scan-chunked ``bloom_contains_keys_st``
+        (or the host engine); coalesced and replicated engines keep the
+        per-batch pipelined form."""
         from redisson_tpu.executor.tpu_executor import defer_host_fetch
 
         batches = list(batches)
-        eng = self._engine
-        executor = getattr(eng, "executor", None)
         single_launch_ok = (
-            getattr(eng, "coalescer", None) is None
+            getattr(self._engine, "coalescer", None) is None
             and not self.is_replicated()
-            and (
-                executor is None  # host engine: one vectorized call
-                or getattr(executor, "supports_device_hash", False)
-            )
         )
         if (
             single_launch_ok

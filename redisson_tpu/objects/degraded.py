@@ -60,7 +60,7 @@ class BloomMirror:
 
     def mixed(self, h1m, h2m, is_add) -> np.ndarray:
         """Sequential add/contains batch — exact arrival-order semantics,
-        matching the device's bloom_mixed contract."""
+        matching the device's bloom_mixed_keys contract."""
         h1m = np.asarray(h1m, np.uint32)
         h2m = np.asarray(h2m, np.uint32)
         is_add = np.asarray(is_add, bool)

@@ -1202,7 +1202,7 @@ class TpuSketchEngine(SketchDurabilityMixin):
 
         Lt = self.executor._trim_lanes(blocks)[0].shape[1]
         const = lengths.ndim == 0 or bool(np.all(lengths == lengths[0]))
-        if getattr(self.executor, "supports_runs_metadata", False):
+        if self.executor.supports_runs_metadata:
             self.prewarmer.register(
                 pool, ("bloom_mixkr", k, L, Lt, const),
                 prewarm.warm_bloom_mixed_keys_runs(k, L, Lt, const),
@@ -1465,20 +1465,10 @@ class TpuSketchEngine(SketchDurabilityMixin):
                 "bloom.init", name,
                 ei=int(expected_insertions), fp=float(false_probability),
             ) if created else None
-        if self.prewarmer is not None:
-            from redisson_tpu.executor import prewarm
-
-            # Pool attach → compile the hashed mixed-kernel ladder in the
-            # background (the keyed/device-hash ladders register on first
-            # sight of a codec signature, _bloom_submit_mixed_keys).
-            self.prewarmer.register(
-                entry.pool, ("bloom_mixed", k), prewarm.warm_bloom_mixed(k)
-            )
+        # Warm ladders register on first sight of a codec signature
+        # (_bloom_submit_mixed_keys): lane count and trim depth come from
+        # real key bytes.
         return self._ack(created, seq)
-
-    def _bloom_reduce(self, entry, H1, H2):
-        m = entry.params["size"]
-        return hashing.km_reduce_mod(H1, H2, m)
 
     def _replication_fence(self, entry, saw_replicas, redispatch) -> None:
         """Close the writer-vs-set_replicated race: a writer that read
@@ -1498,132 +1488,6 @@ class TpuSketchEngine(SketchDurabilityMixin):
             # deadline for exactly this redispatch.
             with _ovl.deadline_scope(None):
                 redispatch()
-
-    def _bloom_dispatch_hashed(self, entry, h1m, h2m, is_add) -> LazyResult:
-        """One mixed-kernel dispatch for hashed ops, honoring replication:
-        replicated entries expand (writes fan to every copy, reads rotate)
-        and results gather back to per-source-op shape."""
-        m, k = entry.params["size"], entry.params["hash_iterations"]
-        B = len(h1m)
-        is_add = np.asarray(is_add, bool)
-        row0 = entry.row  # BEFORE the residency check (see _tier_row)
-        res = self._serve_degraded(
-            entry, B, lambda mir: mir.mixed(h1m, h2m, is_add)
-        )
-        if res is not None:
-            return res
-        orig = (h1m, h2m, is_add)
-        saw_replicas = bool(entry.replica_rows)
-        if saw_replicas:
-            rows, eidx, ppos = self._bloom_expand_ops(entry, B, is_add)
-            h1m, h2m, is_add = h1m[eidx], h2m[eidx], is_add[eidx]
-            gather = lambda v: v[ppos]  # noqa: E731
-        else:
-            rows = np.full(B, self._tier_row(entry, row0), np.int32)
-            gather = None
-        m_arr = np.full(len(rows), m, np.uint32)
-        pool = entry.pool
-        if self.coalescer is not None:
-            # Adds and contains share ONE segment per (pool, k) — the
-            # combined kernel keeps exact arrival-order semantics while
-            # mixed traffic coalesces instead of fragmenting (config 4).
-            fut = self._submit(
-                ("bloom_mix", id(pool), k),
-                lambda cols: self.executor.bloom_mixed(
-                    pool, cols[0], cols[1], k, cols[2], cols[3], cols[4]
-                ),
-                (rows, m_arr, h1m, h2m, is_add),
-                len(rows),
-                pool_key=id(pool),
-                tenant=entry.name,
-            )
-        else:
-            fut = self.executor.bloom_mixed(
-                pool, rows, m_arr, k, h1m, h2m, is_add
-            )
-        if bool(np.any(orig[2])):
-            self._replication_fence(
-                entry,
-                saw_replicas,
-                lambda: self._bloom_dispatch_hashed(entry, *orig),
-            )
-        return fut if gather is None else _MappedFuture(fut, gather)
-
-    def bloom_add(self, name, H1, H2) -> LazyResult:
-        with self._nc_mutate(name), self._journal_gate:
-            entry = self._require(name, PoolKind.BLOOM)
-            h1m, h2m = self._bloom_reduce(entry, H1, H2)
-            m, k = entry.params["size"], entry.params["hash_iterations"]
-            if (
-                not self.config.tpu_sketch.exact_add_semantics
-                and not entry.replica_rows
-                # Degraded: route through the hashed path's mirror
-                # failover instead of hitting the dead device via the
-                # fast-add st dispatch.
-                and not self._degraded(entry)
-            ):
-                # Fast single-tenant bulk path dispatches immediately —
-                # but only after queued coalesced ops flush, so a
-                # contains submitted *before* this add can never observe
-                # its writes (arrival-order contract of the coalescer
-                # docstring).
-                self._drain()
-                res = self.executor.bloom_add_fast_st(
-                    entry.pool, entry.row, m, k, h1m, h2m
-                )
-                self._replication_fence(
-                    entry,
-                    False,
-                    lambda: self._bloom_dispatch_hashed(
-                        entry, h1m, h2m, np.ones(len(H1), bool)
-                    ),
-                )
-            else:
-                res = self._bloom_dispatch_hashed(
-                    entry, h1m, h2m, np.ones(len(H1), bool)
-                )
-            # Journaled PRE-reduce (raw twins): replay re-reduces against
-            # the entry's params, same as the live path.
-            return self._commit(
-                res, "bloom.add", name,
-                h1=np.asarray(H1), h2=np.asarray(H2),
-            )
-
-    def bloom_contains(self, name, H1, H2) -> LazyResult:
-        # Epoch capture BEFORE entry resolution: a delete racing the
-        # lookup bumps epochs in between, and a late capture would tag
-        # the old object's results as fresh for its successor.
-        nc = self.nearcache
-        captured = nc.epochs(name)
-        entry = self._require(name, PoolKind.BLOOM)
-        if nc.active(len(H1)):
-            H1a, H2a = np.asarray(H1), np.asarray(H2)
-            return nc.lookup_batch(
-                "bloom", name, nc.hashed_keys(H1a, H2a), np.bool_,
-                lambda idx: self._bloom_contains_dispatch(
-                    entry,
-                    H1a if idx is None else H1a[idx],
-                    H2a if idx is None else H2a[idx],
-                ),
-                monotone=True, captured=captured,
-            )
-        return self._bloom_contains_dispatch(entry, H1, H2)
-
-    def _bloom_contains_dispatch(self, entry, H1, H2) -> LazyResult:
-        h1m, h2m = self._bloom_reduce(entry, H1, H2)
-        m, k = entry.params["size"], entry.params["hash_iterations"]
-        row0 = entry.row  # BEFORE the residency check (see _tier_row)
-        if (
-            self.coalescer is not None
-            or entry.replica_rows
-            or self._degraded(entry)  # hashed path serves the mirror
-        ):
-            return self._bloom_dispatch_hashed(
-                entry, h1m, h2m, np.zeros(len(H1), bool)
-            )
-        return self.executor.bloom_contains_st(
-            entry.pool, self._tier_row(entry, row0), m, k, h1m, h2m
-        )
 
     def bloom_count(self, name) -> LazyResult:
         nc = self.nearcache
@@ -1649,10 +1513,11 @@ class TpuSketchEngine(SketchDurabilityMixin):
         )
 
     # Encoded entry points: the object layer hands down raw codec lanes and
-    # each engine decides where to hash.  On the direct single-device path
-    # the hash + 64-bit mod run in-kernel (ops/fastpath.py device-hash
-    # path, bit-identical to the host pipeline); coalesced/sharded paths
-    # hash on the host as before.
+    # every Bloom launch hashes them on the device (ops/fastpath.py,
+    # bit-identical to the host pipeline).  The engine picks the program
+    # by what it can observe: coalescer or not, replicas or not, degraded
+    # or not, whether the segment holds an add, and whether the executor
+    # takes runs metadata.
 
     def _runs_dispatch(self, pool, k):
         """Flush-time dispatch for the run-length mixed path: folds the
@@ -1696,7 +1561,7 @@ class TpuSketchEngine(SketchDurabilityMixin):
                     ]
                 )
             if (
-                not getattr(self.executor, "supports_runs_metadata", False)
+                not self.executor.supports_runs_metadata
                 or C > 1024
             ):
                 # Two reasons to expand the runs host-side and take the
@@ -1759,8 +1624,8 @@ class TpuSketchEngine(SketchDurabilityMixin):
                 np.full(B, lengths, np.uint32)
                 if lengths.ndim == 0 else lengths
             )
-            h1m, h2m = self._bloom_reduce(
-                entry, *hashing.hash128_np(blocks, lens)
+            h1m, h2m = hashing.km_reduce_mod(
+                *hashing.hash128_np(blocks, lens), m
             )
             res = self._mirror_call(
                 entry, B, lambda mir: mir.mixed(h1m, h2m, orig_flags)
@@ -1784,7 +1649,7 @@ class TpuSketchEngine(SketchDurabilityMixin):
             self.coalescer is not None
             and not saw_replicas
             and uniform
-            and getattr(self.executor, "supports_runs_metadata", False)
+            and self.executor.supports_runs_metadata
         ):
             # Run-length path: row/m/is_add are constant across this call,
             # so they ride the segment as ONE meta tuple instead of B-long
@@ -1877,45 +1742,18 @@ class TpuSketchEngine(SketchDurabilityMixin):
             return self._bloom_mixed_encoded(name, blocks, lengths, flags)
 
     def _bloom_add_encoded(self, name, blocks, lengths) -> LazyResult:
-        if self.executor.supports_device_hash:
-            with self._nc_mutate(name), self._journal_gate:
-                entry = self._require(name, PoolKind.BLOOM)
-                if (
-                    self.coalescer is not None
-                    and self.config.tpu_sketch.exact_add_semantics
-                ) or entry.replica_rows or self._degraded(entry):
-                    # The mixed-keys path owns the degraded-mirror failover.
-                    res = self._bloom_submit_mixed_keys(
-                        entry, blocks, lengths, True
-                    )
-                    # Journaled as raw key material (replay hashes
-                    # host-side — bit-identical to the device hash).
-                    return self._commit(
-                        res, "bloom.addk", name,
-                        blocks=np.asarray(blocks),
-                        lengths=np.asarray(lengths),
-                    )
-                if not self.config.tpu_sketch.exact_add_semantics:
-                    m, k = entry.params["size"], entry.params["hash_iterations"]
-                    self._drain()
-                    res = self.executor.bloom_add_keys_st(
-                        entry.pool, entry.row, m, k, blocks, lengths
-                    )
-                    self._replication_fence(
-                        entry,
-                        False,
-                        lambda: self._bloom_submit_mixed_keys(
-                            entry, blocks, lengths, True
-                        ),
-                    )
-                    return self._commit(
-                        res, "bloom.addk", name,
-                        blocks=np.asarray(blocks),
-                        lengths=np.asarray(lengths),
-                    )
-        # Host-hash fallback journals inside bloom_add (one record per
-        # accepted op — never two).
-        return self.bloom_add(name, *hashing.hash128_np(blocks, lengths))
+        with self._nc_mutate(name), self._journal_gate:
+            entry = self._require(name, PoolKind.BLOOM)
+            # The keyed path owns the degraded-mirror failover, the
+            # replica fan-out and, with no coalescer, the direct launch.
+            res = self._bloom_submit_mixed_keys(entry, blocks, lengths, True)
+            # Journaled as raw key material (replay hashes host-side —
+            # bit-identical to the device hash).
+            return self._commit(
+                res, "bloom.addk", name,
+                blocks=np.asarray(blocks),
+                lengths=np.asarray(lengths),
+            )
 
     def collect_results(self, lazies) -> None:
         """Engine-level mailbox collect (policy gate for the bulk APIs):
@@ -1930,8 +1768,6 @@ class TpuSketchEngine(SketchDurabilityMixin):
             pass
 
     def _bloom_contains_encoded(self, name, blocks, lengths) -> LazyResult:
-        if not self.executor.supports_device_hash:
-            return self.bloom_contains(name, *hashing.hash128_np(blocks, lengths))
         nc = self.nearcache
         captured = nc.epochs(name)  # before entry resolution, see contains
         entry = self._require(name, PoolKind.BLOOM)
@@ -1990,15 +1826,7 @@ class TpuSketchEngine(SketchDurabilityMixin):
             lens_arr = np.asarray(lengths, np.uint32)
             if lens_arr.ndim == 0:
                 lens_arr = np.full(blocks.shape[0], lens_arr, np.uint32)
-            if not self.executor.supports_device_hash:
-                h1m, h2m = self._bloom_reduce(
-                    entry, *hashing.hash128_np(blocks, lens_arr)
-                )
-                res = self._bloom_dispatch_hashed(entry, h1m, h2m, flags)
-            else:
-                res = self._bloom_submit_mixed_keys(
-                    entry, blocks, lengths, flags
-                )
+            res = self._bloom_submit_mixed_keys(entry, blocks, lengths, flags)
             return self._commit(
                 res, "bloom.addk", name,
                 blocks=np.asarray(blocks)[flags],
@@ -2062,7 +1890,7 @@ class TpuSketchEngine(SketchDurabilityMixin):
         return self.executor.hll_add_single(entry.pool, entry.row, c0, c1, c2)
 
     def hll_add_encoded(self, name, blocks, lengths) -> LazyResult:
-        if self.coalescer is None and self.executor.supports_device_hash:
+        if self.coalescer is None:
             with self._nc_mutate(name), self._journal_gate:
                 entry = self.hll_ensure(name)
                 if not self._degraded(entry):
@@ -2197,7 +2025,7 @@ class TpuSketchEngine(SketchDurabilityMixin):
                 self._prewarm_seen.add(coarse)
                 from redisson_tpu.executor import prewarm
 
-                if getattr(self.executor, "supports_runs_metadata", False):
+                if self.executor.supports_runs_metadata:
                     self.prewarmer.register(
                         entry.pool, ("bs_mixed_runs",),
                         prewarm.warm_bitset_mixed_runs(),
@@ -2292,7 +2120,7 @@ class TpuSketchEngine(SketchDurabilityMixin):
         >1024 runs expand to per-op arrays so the runs kernel's Cp
         compile space stays the single pre-warmed 1024 bucket)."""
         if (
-            getattr(self.executor, "supports_runs_metadata", False)
+            self.executor.supports_runs_metadata
             and len(runs) <= 1024
         ):
             run_rows = np.array([r for _, r, _ in runs], np.int32)

@@ -1,22 +1,18 @@
-"""Single-tenant fast-path bloom kernels — the bulk/bench hot path.
+"""Device-hash kernels: raw codec lanes in, murmur3 + exact 64-bit mod
+on the device, then the ops/bloom.py (or ops/hll.py) kernels.
 
-Design rationale (measured on the v5e chip): the exact sort-based add in
-ops/bitops.py pays an O(B·k log) lexicographic sort per batch for exact
-sequential duplicate semantics; this path instead materializes the batch's
-bits in an int8 bit-delta ([bits/128, 128] rows, scatter-MAX of one-hot
-rows — idempotent, so duplicate bits need no dedup), packs it to uint32
-words with a weighted lane reduction, and ORs it into the tenant row.
-~4x faster adds.
+Every Bloom op of the TPU engine runs one of these.  The host ships key
+bytes only (no 16-byte host hashes, no producer-thread hashing), and the
+in-kernel hash is bit-identical to the host pipeline
+(``hashing.hash128_np`` + ``hashing.km_reduce_mod``), so a device answer
+equals the golden model's.
 
-Semantic difference (documented, opt-in via
-``Config.use_tpu_sketch(exact_add_semantics=False)``): the returned
-``newly_added`` flags are computed against the PRE-BATCH state — two
-identical keys in one batch both report True, where the exact path reports
-True then False.  Bit-level results are identical; only duplicate-key
-flags within a single batch differ.
-
-The single-tenant restriction keeps the bit-delta at one row's size
-(m/8 bytes); multi-tenant coalesced batches use the exact path.
+- ``bloom_mixed_keys``: multi-tenant add/contains mix with exact
+  sequential semantics (ops/bloom.bloom_mixed's sort-chunked scan).
+- ``bloom_contains_keys``: multi-tenant contains-only batch as one row
+  gather, sliced by ``GATHER_SLICE``.
+- ``bloom_contains_keys_st``: one tenant's contains, row and m as scalars.
+- ``hll_add_keys_single``: one HyperLogLog's PFADD.
 """
 
 from __future__ import annotations
@@ -32,57 +28,6 @@ from redisson_tpu.ops import bitops
 # launch took 64 ms at 16,384 ops a slice, 73 at 65,536, 76 at 262,144
 # and 74 unsliced (pipelined, wall clock), so the shortest of those.
 GATHER_SLICE = 1 << 14
-
-
-def bloom_add_fast_st(flat_words, row, h1m, h2m, m, valid, *, k: int, words_per_row: int):
-    """Single-tenant bulk add.  row and m are traced scalars (no per-op
-    arrays to transfer).  Returns (new_flat, newly bool[B] vs pre-batch).
-    """
-    B = h1m.shape[0]
-    idx = bitops.expand_km_indexes(h1m, h2m, m, k)  # [B, k] bit indexes
-    # Pre-batch membership for newly flags (row gathers, exact).
-    base_word = row.astype(jnp.uint32) * np.uint32(words_per_row)
-    gword = base_word + (idx >> np.uint32(5))
-    bit = idx & np.uint32(31)
-    pre = bitops.gather_bits(flat_words, gword.reshape(-1), bit.reshape(-1))
-    newly = (pre == 0).reshape(B, k).any(axis=1)
-
-    # int8 bit-delta over this tenant's row only, plus one padding row that
-    # absorbs invalid (batch-padding) ops.
-    rb = words_per_row * 32 // 128  # bit-rows in one tenant row
-    local_bit = idx.reshape(-1)
-    brow = (local_bit >> np.uint32(7)).astype(jnp.int32)
-    if valid is not None:
-        valid_flat = jnp.broadcast_to(valid[:, None], idx.shape).reshape(-1)
-        brow = jnp.where(valid_flat, brow, rb)
-    lane = (local_bit & np.uint32(127)).astype(jnp.int32)
-    onehot = (
-        jnp.arange(128, dtype=jnp.int32)[None, :] == lane[:, None]
-    ).astype(jnp.int8)
-    delta8 = jnp.zeros((rb + 1, 128), jnp.int8).at[brow].max(onehot)
-    # Pack 128 bits/row -> 4 uint32 words/row (weighted lane reduction).
-    weights = (np.uint32(1) << np.arange(32, dtype=np.uint32))[None, None, :]
-    packed = (delta8[:rb].reshape(rb, 4, 32).astype(jnp.uint32) * weights).sum(
-        axis=-1, dtype=jnp.uint32
-    )
-    delta_words = packed.reshape(-1)  # [words_per_row]
-
-    cur = bitops.row_slice(flat_words, row, words_per_row)
-    new = bitops.row_update(flat_words, row, cur | delta_words, words_per_row)
-    return new, newly
-
-
-def bloom_contains_st(flat_words, row, h1m, h2m, m, *, k: int, words_per_row: int):
-    """Single-tenant contains with scalar row/m operands (halves the H2D
-    transfer volume vs the per-op array form).  Bit-exact with
-    ops/bloom.bloom_contains."""
-    B = h1m.shape[0]
-    idx = bitops.expand_km_indexes(h1m, h2m, m, k)
-    base_word = row.astype(jnp.uint32) * np.uint32(words_per_row)
-    gword = base_word + (idx >> np.uint32(5))
-    bit = idx & np.uint32(31)
-    bits = bitops.gather_bits(flat_words, gword.reshape(-1), bit.reshape(-1))
-    return bits.reshape(B, k).all(axis=1)
 
 
 # --------------------------------------------------------------------------
@@ -142,20 +87,15 @@ def _hash_km_device(blocks, lengths, m, target_lanes: int):
     return h1m, h2m
 
 
-def bloom_add_keys_st(flat_words, row, blocks, lengths, m, valid, *, k: int, words_per_row: int, target_lanes: int):
-    """Single-tenant bulk add from raw key lanes (device-side hashing)."""
-    h1m, h2m = _hash_km_device(blocks, lengths, m, target_lanes)
-    return bloom_add_fast_st(
-        flat_words, row, h1m, h2m, m, valid, k=k, words_per_row=words_per_row
-    )
-
-
 def bloom_contains_keys_st(flat_words, row, blocks, lengths, m, *, k: int, words_per_row: int, target_lanes: int):
-    """Single-tenant contains from raw key lanes (device-side hashing)."""
+    """Single-tenant contains from raw key lanes (device-side hashing);
+    ``row`` and ``m`` are scalars, so no per-op column ships."""
+    from redisson_tpu.ops import bloom
+
     h1m, h2m = _hash_km_device(blocks, lengths, m, target_lanes)
-    return bloom_contains_st(
-        flat_words, row, h1m, h2m, m, k=k, words_per_row=words_per_row
-    )
+    rows = jnp.broadcast_to(row, h1m.shape)
+    return bloom.bloom_contains(
+        flat_words, rows, h1m, h2m, m=m, k=k, words_per_row=words_per_row)
 
 
 def bloom_contains_keys(flat_words, rows, blocks, lengths, m_arr, *, k: int, words_per_row: int, target_lanes: int):

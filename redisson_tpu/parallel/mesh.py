@@ -272,8 +272,7 @@ def _psharded(ctx: MeshContext, inner, n_op_args: int, *, out_state: bool, donat
 # [W, Bp] slice packs its ops' columns as the one-chip staging block does,
 # a word row per column, so the op axis stays the minor one.  Rows 0-2 are every op's local
 # row, m and flags (BLOOM_ADD | BLOOM_VALID; a padded slot is all zero
-# but m = 1), then the payload: h1m, h2m on the host-hash path, or the
-# key length and the trimmed key lanes on the device-hash path.
+# but m = 1), then the payload: the key length and the trimmed key lanes.
 BLOOM_HEAD = 3
 BLOOM_ADD, BLOOM_VALID = 1, 2
 
@@ -286,26 +285,10 @@ def _bloom_head(blk):
             blk[BLOOM_HEAD:])
 
 
-def psharded_bloom_mixed(ctx: MeshContext, *, k: int, words_per_row: int):
-    """fn(state, block[S, 5, Bp]) -> (new_state, packed[S, Bp/32]); the
-    block's payload rows are h1m, h2m; rows already shard-local."""
-
-    def inner(local, blk):
-        lrows, m_arr, is_add, valid, (h1m, h2m) = _bloom_head(blk)
-        new_local, res = bloom.bloom_mixed(
-            local, lrows, h1m, h2m, is_add,
-            m=m_arr, k=k, words_per_row=words_per_row, valid=valid,
-        )
-        return new_local[None], bitops.pack_bool_u32(res)[None]
-
-    return _psharded(ctx, inner, 1, out_state=True, name="psharded_bloom_mixed")
-
-
 def psharded_bloom_mixed_keys(ctx: MeshContext, *, k: int, words_per_row: int, target_lanes: int):
-    """Device-hash variant: the block's payload rows are the key length
-    and the trimmed codec lanes ([S, 4 + Lt, Bp]), hashed in-kernel (the
-    round-2 sharded mode shipped 16-byte host hashes — the fast path now
-    works sharded too)."""
+    """fn(state, block[S, 4 + Lt, Bp]) -> (new_state, packed[S, Bp/32]):
+    the block's payload rows are the key length and the trimmed codec
+    lanes, hashed in-kernel; rows already shard-local."""
     from redisson_tpu.ops import fastpath
 
     def inner(local, blk):

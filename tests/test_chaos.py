@@ -96,10 +96,10 @@ class TestSchedule:
     def test_prefix_match_for_dispatch_points(self):
         chaos.inject("dispatch", kind="error", rate=1.0)
         with pytest.raises(FaultInjected):
-            chaos.fire("dispatch.bloom_mixed")
+            chaos.fire("dispatch.bloom_mixed_keys")
         chaos.clear()
         chaos.inject("dispatch.read_row", kind="error", rate=1.0)
-        chaos.fire("dispatch.bloom_mixed")  # no rule for this method
+        chaos.fire("dispatch.bloom_mixed_keys")  # no rule for this method
         with pytest.raises(FaultInjected):
             chaos.fire("dispatch.read_row")
 
@@ -263,7 +263,7 @@ class TestBreaker:
 
 
 BLOOM_POINTS = (
-    "dispatch.bloom_mixed", "dispatch.bloom_mixed_keys",
+    "dispatch.bloom_mixed_keys", "dispatch.bloom_contains_keys_st",
     "dispatch.bloom_mixed_keys_runs", "dispatch.bloom_contains_keys_runs",
 )
 
@@ -432,10 +432,10 @@ class TestDegradedServe:
                 return line.strip()
 
             # DEBUG INJECT arms a rule; LIST shows it; OFF clears.
-            assert cmd("DEBUG", "INJECT", "dispatch.bloom_mixed", "error",
-                       "1.0", "7") == b"+OK"
+            assert cmd("DEBUG", "INJECT", "dispatch.bloom_mixed_keys",
+                       "error", "1.0", "7") == b"+OK"
             assert chaos.active() == {
-                "dispatch.bloom_mixed": ("error", 1.0, 7)
+                "dispatch.bloom_mixed_keys": ("error", 1.0, 7)
             }
             info = cmd("INFO", "stats").decode()
             assert "degraded:0" in info
@@ -574,25 +574,37 @@ class TestDegradedKinds:
         assert _await(lambda: eng.health.any_degraded)
 
     def test_bloom_fast_paths_degrade_too(self):
-        """exact_add_semantics=False routes adds through the fast
-        single-tenant device path — once the kind degrades it must fail
-        over to the mirror like every other bloom op."""
+        """A direct engine (no coalescer) launches its keyed add
+        (bloom_mixed_keys) and contains (bloom_contains_keys_st) itself.
+        Once the kind degrades, both must fail over to the mirror like
+        every other bloom op: with every Bloom dispatch point faulted,
+        nothing may reach the device until the breaker closes."""
         c = make_client(
             breaker_failure_threshold=2, breaker_open_ms=1500,
-            exact_add_semantics=False,
+            coalesce=False,
         )
         eng = c._engine
         try:
+            assert eng.coalescer is None
             bf = c.get_bloom_filter("fast-bf")
             bf.try_init(10_000, 0.01)
             bf.add("pre")
-            # Open the breaker via the coalesced contains path.
-            self._degrade(
-                eng, lambda: bf.contains("x"), BLOOM_POINTS, seed=11,
-            )
+            chaos.install(ChaosSchedule(seed=11, rate=1.0,
+                                        points=BLOOM_POINTS))
+            # A direct dispatch raises to its caller and feeds no
+            # breaker, so open the Bloom breaker as a coalesced flush's
+            # failures would.
+            with pytest.raises(FaultInjected):
+                bf.add("x")
+            for _ in range(2):
+                eng.health.record_failure(
+                    "bloom_mixed_keys", FaultInjected("dispatch"))
+            assert _await(lambda: eng.health.any_degraded)
             assert _flap(lambda: bf.add("down-add")) is True  # mirror
+            assert _flap(lambda: bf.add("down-add")) is False
             assert _flap(lambda: bf.contains("down-add")) is True
             assert _flap(lambda: bf.contains("pre")) is True
+            assert not _flap(lambda: bf.contains("never-added"))
             chaos.clear()
             assert _await(lambda: not eng.health.any_degraded)
             assert bf.contains("down-add") and bf.contains("pre")

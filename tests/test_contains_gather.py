@@ -20,7 +20,7 @@ FILTERS = (("g0", 10_000), ("g1", 9_000), ("g2", 7_000))
 def _tpu(codec, **kw):
     kw.setdefault("batch_window_us", 500)
     return redisson_tpu.create(Config().set_codec(codec).use_tpu_sketch(
-        coalesce=True, exact_add_semantics=True, min_bucket=64, **kw))
+        coalesce=True, min_bucket=64, **kw))
 
 
 def _keys(codec, lo, n):

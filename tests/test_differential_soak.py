@@ -18,7 +18,7 @@ def test_differential_soak(shards, coalesce):
     tpu = redisson_tpu.create(
         Config().set_codec(LongCodec()).use_tpu_sketch(
             min_bucket=64, num_shards=shards, coalesce=coalesce,
-            exact_add_semantics=True, batch_window_us=100, max_batch=4096,
+            batch_window_us=100, max_batch=4096,
         )
     )
     host = redisson_tpu.create(Config().set_codec(LongCodec()))

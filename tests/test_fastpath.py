@@ -1,118 +1,21 @@
-"""Fast-path kernels: bit-exact state, documented snapshot newly semantics."""
+"""Device-hash kernels (ops/fastpath.py): bit-identical to the host hash
+pipeline, with exact sequential add semantics on every engine path."""
 
 import numpy as np
-import jax.numpy as jnp
+import pytest
 
 import redisson_tpu
 from redisson_tpu import Config
-from redisson_tpu.ops import bloom, fastpath, golden
-from redisson_tpu.utils import hashing
-
-
-def _hashes(n, seed, m):
-    rng = np.random.default_rng(seed)
-    keys = rng.integers(0, 1 << 63, size=n, dtype=np.uint64)
-    blocks, lengths = hashing.encode_uint64_batch(keys)
-    h1, h2 = hashing.hash128_np(blocks, lengths)
-    return hashing.km_reduce_mod(h1, h2, m)
-
-
-def test_fast_add_bit_exact_vs_exact_kernel():
-    M, K, W = 1 << 16, 7, (1 << 16) // 32
-    pool_a = jnp.zeros((2 * W + 1,), jnp.uint32)
-    pool_b = jnp.zeros((2 * W + 1,), jnp.uint32)
-    h1m, h2m = _hashes(700, 3, M)
-    rows = np.ones(700, np.int32)  # tenant row 1
-    # exact kernel
-    pool_a, newly_a = bloom.bloom_add(
-        pool_a, jnp.asarray(rows), jnp.asarray(h1m), jnp.asarray(h2m),
-        m=M, k=K, words_per_row=W,
-    )
-    # fast single-tenant kernel
-    pool_b, newly_b = fastpath.bloom_add_fast_st(
-        pool_b, np.int32(1), jnp.asarray(h1m), jnp.asarray(h2m), np.uint32(M),
-        None, k=K, words_per_row=W,
-    )
-    np.testing.assert_array_equal(np.asarray(pool_a)[:-1], np.asarray(pool_b)[:-1])
-    # unique random keys: newly flags agree too
-    np.testing.assert_array_equal(np.asarray(newly_a), np.asarray(newly_b))
-    # contains_st agrees with exact contains
-    got = fastpath.bloom_contains_st(
-        pool_b, np.int32(1), jnp.asarray(h1m), jnp.asarray(h2m), np.uint32(M),
-        k=K, words_per_row=W,
-    )
-    assert np.asarray(got).all()
-
-
-def test_fast_add_snapshot_duplicate_semantics():
-    M, K, W = 1 << 16, 5, (1 << 16) // 32
-    pool = jnp.zeros((W + 1,), jnp.uint32)
-    h1m = jnp.asarray(np.array([9, 9], np.uint32))
-    h2m = jnp.asarray(np.array([3, 3], np.uint32))
-    pool, newly = fastpath.bloom_add_fast_st(
-        pool, np.int32(0), h1m, h2m, np.uint32(M), None, k=K, words_per_row=W
-    )
-    # Snapshot semantics: both duplicates report newly=True.
-    assert np.asarray(newly).tolist() == [True, True]
-    # Second batch: nothing newly.
-    pool, newly2 = fastpath.bloom_add_fast_st(
-        pool, np.int32(0), h1m, h2m, np.uint32(M), None, k=K, words_per_row=W
-    )
-    assert np.asarray(newly2).tolist() == [False, False]
-
-
-def test_fast_add_padding_mask():
-    M, K, W = 1 << 16, 5, (1 << 16) // 32
-    pool = jnp.zeros((W + 1,), jnp.uint32)
-    h1m = jnp.asarray(np.array([0, 0], np.uint32))
-    h2m = jnp.asarray(np.array([1, 0], np.uint32))
-    valid = jnp.asarray(np.array([True, False]))
-    pool, _ = fastpath.bloom_add_fast_st(
-        pool, np.int32(0), h1m, h2m, np.uint32(M), valid, k=K, words_per_row=W
-    )
-    g = golden.GoldenBloomFilter(M, K)
-    g.add_hashed(np.array([0], np.uint32), np.array([1], np.uint32))
-    bits = np.unpackbits(np.asarray(pool)[:-1].view(np.uint8), bitorder="little")
-    np.testing.assert_array_equal(bits.astype(bool), g.bits)
-
-
-def test_fast_mode_e2e_parity_with_host():
-    keys = [f"k{i}" for i in range(3000)]
-    ghosts = [f"g{i}" for i in range(3000)]
-    results = {}
-    for mode in ("fast", "host"):
-        cfg = Config()
-        if mode == "fast":
-            cfg.use_tpu_sketch(min_bucket=64, exact_add_semantics=False)
-        cl = redisson_tpu.create(cfg)
-        bf = cl.get_bloom_filter("fp")
-        bf.try_init(3000, 0.01)
-        added = bf.add_all(keys)
-        if mode == "fast":
-            # Snapshot semantics: unique keys vs empty pre-state all count.
-            assert added == 3000
-        else:
-            # Sequential semantics may mark a few late keys as dups (all k
-            # bits already set by earlier keys).
-            assert 2900 <= added <= 3000
-        results[mode] = (
-            np.asarray(bf.contains_each(keys)),
-            np.asarray(bf.contains_each(ghosts)),
-        )
-    np.testing.assert_array_equal(results["fast"][0], results["host"][0])
-    np.testing.assert_array_equal(results["fast"][1], results["host"][1])
+from redisson_tpu.codecs import LongCodec
 
 
 def test_device_hash_path_matches_host_engine():
-    """The *_keys_st device-hash kernels (murmur + 64-bit mod in-kernel)
-    must be bit-identical to the host hash pipeline: same membership
-    answers, same newly flags, same HLL changed booleans."""
-    import redisson_tpu
-    from redisson_tpu import Config
-
+    """A direct engine's device-hash launches (murmur + 64-bit mod
+    in-kernel) must be bit-identical to the host hash pipeline: same
+    membership answers, same newly flags, same HLL changed booleans."""
     results = {}
     for mode, kwargs in (
-        ("devhash", dict(exact_add_semantics=False, coalesce=False)),
+        ("devhash", dict(coalesce=False)),
         ("host", None),
     ):
         cfg = Config()
@@ -151,3 +54,39 @@ def test_mod64_bits_exact():
         )
         h64 = (hi.astype(np.uint64) << np.uint64(32)) | lo.astype(np.uint64)
         np.testing.assert_array_equal(got, (h64 % np.uint64(m)).astype(np.uint32))
+
+
+def test_exact_add_semantics_key_is_unknown():
+    """The inexact-add switch is gone: naming it fails like any unknown
+    tpuSketch key."""
+    with pytest.raises(ValueError, match="unknown tpuSketch config key"):
+        Config().use_tpu_sketch(exact_add_semantics=False)
+
+
+@pytest.mark.parametrize("num_shards", [1, 4])
+def test_direct_add_duplicate_keys_are_exact(num_shards):
+    """A direct engine (no coalescer) on one device or four answers a
+    batch that holds the same key twice as the host engine does: True
+    for the first add, False for the repeat."""
+    keys = np.array([11, 12, 11, 13, 12, 11], np.uint64)
+    results = {}
+    for mode, kwargs in (
+        ("direct", dict(min_bucket=64, coalesce=False,
+                        num_shards=num_shards)),
+        ("host", None),
+    ):
+        cfg = Config().set_codec(LongCodec())
+        if kwargs is not None:
+            cfg.use_tpu_sketch(**kwargs)
+        cl = redisson_tpu.create(cfg)
+        try:
+            bf = cl.get_bloom_filter("dup")
+            bf.try_init(1000, 0.01)
+            newly = np.asarray(bf.add_all_async(keys).result())
+            again = np.asarray(bf.add_all_async(keys[:2]).result())
+            results[mode] = (newly.tolist(), again.tolist(),
+                             bf.contains_each(keys).tolist())
+        finally:
+            cl.shutdown()
+    assert results["direct"][0] == [True, True, False, True, False, False]
+    assert results["direct"] == results["host"]

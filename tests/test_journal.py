@@ -24,6 +24,7 @@ from redisson_tpu.durability.journal import (
     decode_record,
     encode_record,
 )
+from redisson_tpu.utils import hashing
 
 
 def make_cfg(tmp_path, fsync="always", journal=True, snap=True, **kw):
@@ -415,13 +416,56 @@ class TestEngineRecovery:
         )[0]) == [5]
         crash(c2)
 
+    def test_direct_add_journals_keyed_record(self, tmp_path):
+        """A direct engine (no coalescer) journals its adds as raw key
+        material (bloom.addk), like the coalesced engine; replay restores
+        the same membership and bits."""
+        c1 = make_client(tmp_path, snap=False, coalesce=False)
+        bf = c1.get_bloom_filter("bf")
+        bf.try_init(1000, 0.01)
+        bf.add_all(list(range(50)))
+        bf.add(7)
+        ops = [rec["op"] for _, rec in c1._engine.journal.records_after(0)]
+        assert ops == ["bloom.init", "bloom.addk", "bloom.addk"]
+        want = engine_rows(c1._engine)
+        crash(c1)
+        c2 = make_client(tmp_path, snap=False, coalesce=False)
+        got = engine_rows(c2._engine)
+        np.testing.assert_array_equal(got["bf"], want["bf"])
+        bf2 = c2.get_bloom_filter("bf")
+        assert all(bf2.contains_each(list(range(50))))
+        assert not bf2.contains(987654)
+        crash(c2)
+
+    def test_hashed_bloom_add_record_still_replays(self, tmp_path):
+        """Journals written before every add was keyed hold host-hashed
+        bloom.add records (raw 64-bit hash twins); recovery replays them
+        to the same bits a keyed add of those keys sets."""
+        keys = np.arange(40, dtype=np.uint64) + np.uint64(1000)
+        c1 = make_client(tmp_path, snap=False)
+        c1.get_bloom_filter("old").try_init(1000, 0.01)
+        c1.get_bloom_filter("new").try_init(1000, 0.01)
+        h1, h2 = hashing.hash128_np(*hashing.encode_uint64_batch(keys))
+        j = c1._engine.journal
+        assert j.wait_durable(
+            j.append({"op": "bloom.add", "name": "old", "h1": h1, "h2": h2}),
+            timeout=10.0)
+        c1.get_bloom_filter("new").add_all(list(keys))
+        crash(c1)
+        c2 = make_client(tmp_path, snap=False)
+        got = engine_rows(c2._engine)
+        assert got["old"].any()
+        np.testing.assert_array_equal(got["old"], got["new"])
+        assert all(c2.get_bloom_filter("old").contains_each(list(keys)))
+        crash(c2)
+
     def test_always_future_done_tracks_durability(self, tmp_path):
         c1 = make_client(tmp_path, snap=False, fsync="always")
         eng = c1._engine
         bf = c1.get_bloom_filter("bf")
         bf.try_init(1000, 0.01)
-        res = eng.bloom_add("bf", np.array([1], np.uint64),
-                            np.array([2], np.uint64))
+        res = eng.bloom_add_encoded(
+            "bf", *hashing.encode_uint64_batch(np.array([1], np.uint64)))
         from redisson_tpu.objects.engines import _DurableResult
 
         assert isinstance(res, _DurableResult)
@@ -500,7 +544,6 @@ class TestRecoveryEdgeCases:
         bf = c1.get_bloom_filter("bf")
         bf.try_init(10_000, 0.01)
         bf.add(1)
-        chaos.inject("dispatch.bloom_mixed", kind="error", rate=1.0)
         chaos.inject("dispatch.bloom_mixed_keys", kind="error", rate=1.0)
         for point in ("dispatch.bloom_mixed_keys_runs",
                       "dispatch.bloom_contains_keys_runs"):

@@ -33,6 +33,7 @@ from redisson_tpu.obs import spans as spans_mod
 from redisson_tpu.serve.resp import RespServer
 from redisson_tpu.serve.wireutil import skip_reply_frame, wire_command
 from redisson_tpu.tenancy import PoolKind
+from redisson_tpu.utils import hashing
 
 
 def _capture(log_dir, body):
@@ -330,6 +331,12 @@ def test_gc_pause_and_collections_once_per_collection():
             gc.enable()
 
 
+def _key_block(B):
+    """B LongCodec keys whose two low lanes are both in use (Lt = 2)."""
+    keys = np.arange(B, dtype=np.uint64) + np.uint64(1 << 40)
+    return hashing.encode_uint64_batch(keys)
+
+
 def test_h2d_bytes_recorded_once_per_dispatch():
     cl = _client(coalesce=False)
     obs = cl._engine.obs
@@ -341,18 +348,22 @@ def test_h2d_bytes_recorded_once_per_dispatch():
         m, k = entry.params["size"], entry.params["hash_iterations"]
         B = 100
         rows = np.full(B, entry.row, np.int32)
-        h = np.arange(B, dtype=np.uint32)
+        blocks, lengths = _key_block(B)
         m_arr = np.full(B, m, np.uint32)
+        add = np.ones(B, bool)
 
         def shipped():
-            return obs.h2d_bytes.get(("bloom_add",))
+            return obs.h2d_bytes.get(("bloom_mixed_keys",))
 
-        ex.bloom_add(entry.pool, rows, m_arr, k, h, h).result()
+        ex.bloom_mixed_keys(entry.pool, rows, m_arr, k, blocks, lengths,
+                            add).result()
         one = shipped()
         Bp = ex._bucket(B)
-        # rows, h1, h2, m: 4 bytes an op; the validity mask: 1.
-        assert one == 4 * 4 * Bp + Bp
-        ex.bloom_add(entry.pool, rows, m_arr, k, h, h).result()
+        # One packed block: the op count, then rows, lengths and m at 4
+        # bytes an op, the add flags at 1 bit, the two key lanes at 8.
+        assert one == 4 * (1 + 3 * Bp + Bp // 32 + 2 * Bp)
+        ex.bloom_mixed_keys(entry.pool, rows, m_arr, k, blocks, lengths,
+                            add).result()
         assert shipped() == 2 * one
     finally:
         cl.shutdown()
@@ -371,15 +382,19 @@ def test_compiles_count_a_new_shape_once_under_its_method():
         m, k = entry.params["size"], entry.params["hash_iterations"]
         B = 5000  # bucket 8192: no other test in this file uses it
         rows = np.full(B, entry.row, np.int32)
-        h = np.arange(B, dtype=np.uint32)
+        blocks, lengths = _key_block(B)
         m_arr = np.full(B, m, np.uint32)
-        n0 = obs.compiles.get(("bloom_add",))
+        add = np.ones(B, bool)
+        method = ("bloom_mixed_keys",)
+        n0 = obs.compiles.get(method)
         s0 = obs.compile_seconds.get()
-        ex.bloom_add(entry.pool, rows, m_arr, k, h, h).result()
-        assert obs.compiles.get(("bloom_add",)) - n0 == 1
+        ex.bloom_mixed_keys(entry.pool, rows, m_arr, k, blocks, lengths,
+                            add).result()
+        assert obs.compiles.get(method) - n0 == 1
         assert obs.compile_seconds.get() > s0
-        ex.bloom_add(entry.pool, rows, m_arr, k, h, h).result()
-        assert obs.compiles.get(("bloom_add",)) - n0 == 1
+        ex.bloom_mixed_keys(entry.pool, rows, m_arr, k, blocks, lengths,
+                            add).result()
+        assert obs.compiles.get(method) - n0 == 1
     finally:
         cl.shutdown()
 

@@ -98,7 +98,7 @@ def test_contains_many_host_engine():
 def test_coalesced_hammer_parity(mailbox):
     c = make_client(
         coalesce=True, batch_window_us=100, max_batch=4096,
-        mailbox_collect=mailbox, exact_add_semantics=True,
+        mailbox_collect=mailbox,
     )
     try:
         filters = [c.get_bloom_filter(f"mbham{i}") for i in range(8)]

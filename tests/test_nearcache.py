@@ -592,7 +592,7 @@ class TestLocalCachedMapSharedStore:
 
 
 BLOOM_POINTS = (
-    "dispatch.bloom_mixed", "dispatch.bloom_mixed_keys",
+    "dispatch.bloom_mixed_keys", "dispatch.bloom_contains_keys_st",
     "dispatch.bloom_mixed_keys_runs", "dispatch.bloom_contains_keys_runs",
 )
 BITSET_POINTS = ("dispatch.bitset_mixed", "dispatch.bitset_mixed_runs")

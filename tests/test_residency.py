@@ -561,7 +561,7 @@ def test_differential_soak_vs_golden_with_forced_transitions(tmp_path):
                 # NOT degraded — the soak exercises both on one
                 # keyspace).
                 chaos.inject(
-                    "dispatch.bloom_mixed", kind="error", rate=1.0,
+                    "dispatch.bloom_mixed_keys_runs", kind="error", rate=1.0,
                     seed=step,
                 )
                 degraded_until = time.monotonic() + 0.2

@@ -165,16 +165,15 @@ def test_cms_counts_wrap_identically_between_modes():
 
 
 def test_fast_add_drains_pending_coalesced_reads():
-    """ADVICE r1: with exact_add_semantics=False + coalescing on, a fast
-    add must not overtake an earlier queued contains."""
+    """ADVICE r1: on the default (coalescing, exact) config an add must
+    not overtake an earlier queued contains of the same key."""
     cfg = Config().use_tpu_sketch(
-        exact_add_semantics=False, coalesce=True,
-        batch_window_us=200_000, min_bucket=64,
+        coalesce=True, batch_window_us=200_000, min_bucket=64,
     )
     cl = redisson_tpu.create(cfg)
     bf = cl.get_bloom_filter("orderbf")
     bf.try_init(1000, 0.01)
-    # Queue a contains (sits in the window), then fast-add the same key.
+    # Queue a contains (sits in the window), then add the same key.
     fut = bf.contains_async("late-key")
     bf.add("late-key")
     # The earlier read must NOT observe the later write.

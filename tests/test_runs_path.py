@@ -13,7 +13,7 @@ from redisson_tpu.codecs import LongCodec
 def _client(**kw):
     kw.setdefault("batch_window_us", 500)
     cfg = Config().set_codec(LongCodec()).use_tpu_sketch(
-        coalesce=True, exact_add_semantics=True, min_bucket=64, **kw
+        coalesce=True, min_bucket=64, **kw
     )
     return redisson_tpu.create(cfg)
 
@@ -79,7 +79,7 @@ def test_runs_mixed_add_contains_order_within_segment():
 def test_runs_variable_length_keys():
     """String keys with differing lengths force the per-op lengths path."""
     cfg = Config().use_tpu_sketch(
-        coalesce=True, exact_add_semantics=True, min_bucket=64,
+        coalesce=True, min_bucket=64,
         batch_window_us=500,
     )
     c = redisson_tpu.create(cfg)

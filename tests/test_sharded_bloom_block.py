@@ -1,10 +1,11 @@
 """Partitioned Bloom launches on a four-shard virtual CPU mesh ship one
 packed input block, placed with the pool state's sharding.
 
-Gates: answers of both sharded Bloom kernels (device-hash
-``bloom_mixed_keys``, host-hash ``bloom_mixed``) equal the golden model
-(``ops/golden.py``, the host engine's reference) applied op by op in
-arrival order; every launch places exactly one input block, committed
+Gates: answers of the sharded Bloom kernel (``bloom_mixed_keys``) for
+both key shapes that reach it (8-byte LongCodec keys with one scalar
+length, variable-length string keys with a length an op and more lanes)
+equal the golden model (``ops/golden.py``, the host engine's reference)
+applied op by op in arrival order; every launch places exactly one input block, committed
 to the state's sharding, and counts one ``rtpu_h2d_puts``; a mesh that
 is not fully addressable keeps the default-device put; one-chip puts
 stay uncommitted.
@@ -74,8 +75,9 @@ class _Golden:
         self.m = m
         self.g = {int(r): golden.GoldenBloomFilter(m, k) for r in rows}
 
-    def run(self, rows, keys, is_add):
-        blocks, lengths = hashing.encode_uint64_batch(keys)
+    def run(self, rows, keys, is_add, shape="u64"):
+        blocks, lengths = _encode(keys, shape)
+        lengths = np.broadcast_to(lengths, blocks.shape[:1])
         h1m, h2m = hashing.km_reduce_mod(
             *hashing.hash128_np(blocks, lengths), self.m)
         out = np.zeros(len(keys), bool)
@@ -86,17 +88,21 @@ class _Golden:
         return out
 
 
-def _launch(ex, pool, rows, m, k, keys, is_add, hashed):
-    B = len(keys)
-    blocks, lengths = hashing.encode_uint64_batch(keys)
-    m_arr = np.full(B, m, np.uint32)
-    if hashed:
-        h1m, h2m = hashing.km_reduce_mod(
-            *hashing.hash128_np(blocks, lengths), m)
-        lr = ex.bloom_mixed(pool, rows, m_arr, k, h1m, h2m, is_add)
-    else:
-        lr = ex.bloom_mixed_keys(pool, rows, m_arr, k, blocks, lengths,
-                                 is_add)
+def _encode(keys, shape):
+    """(blocks, lengths) of integer keys in one of the two key shapes:
+    ``u64``, LongCodec's 8 bytes with one scalar length (API callers);
+    ``str``, a decimal string padded to 1-39 bytes, one length an op and
+    up to three 16-byte blocks (RESP callers)."""
+    if shape == "u64":
+        return hashing.encode_uint64_batch(keys)[0], np.uint32(8)
+    return hashing.encode_bytes_batch(
+        [b"k%d:" % int(x) + b"x" * (int(x) % 36) for x in keys])
+
+
+def _launch(ex, pool, rows, m, k, keys, is_add, shape="u64"):
+    blocks, lengths = _encode(keys, shape)
+    m_arr = np.full(len(keys), m, np.uint32)
+    lr = ex.bloom_mixed_keys(pool, rows, m_arr, k, blocks, lengths, is_add)
     return np.asarray(lr.result())
 
 
@@ -108,9 +114,8 @@ def _batch(rng, tenant_rows, n, key_space=60):
     return rows, keys, rng.random(n) < 0.4
 
 
-@pytest.mark.parametrize("hashed", [False, True],
-                         ids=["bloom_mixed_keys", "bloom_mixed"])
-def test_packed_launch_matches_golden(sharded, hashed):
+@pytest.mark.parametrize("shape", ["u64", "str"])
+def test_packed_launch_matches_golden(sharded, shape):
     """Mixed add and contains over many tenants, duplicates in a batch,
     several batches on the same state."""
     pool, trows, m, k = _tenants(sharded)
@@ -119,13 +124,12 @@ def test_packed_launch_matches_golden(sharded, hashed):
     rng = np.random.default_rng(7)
     for _ in range(4):
         rows, keys, is_add = _batch(rng, trows, 300)
-        got = _launch(ex, pool, rows, m, k, keys, is_add, hashed)
-        np.testing.assert_array_equal(got, ref.run(rows, keys, is_add))
+        got = _launch(ex, pool, rows, m, k, keys, is_add, shape)
+        np.testing.assert_array_equal(got, ref.run(rows, keys, is_add, shape))
 
 
-@pytest.mark.parametrize("hashed", [False, True],
-                         ids=["bloom_mixed_keys", "bloom_mixed"])
-def test_shard_with_no_ops(sharded, hashed):
+@pytest.mark.parametrize("shape", ["u64", "str"])
+def test_shard_with_no_ops(sharded, shape):
     """Every op of the batch lands on three of the four shards; the
     idle shard's slots are all padding and its rows stay empty."""
     pool, trows, m, k = _tenants(sharded)
@@ -136,18 +140,17 @@ def test_shard_with_no_ops(sharded, hashed):
     ref = _Golden(trows, m, k)
     rng = np.random.default_rng(3)
     rows, keys, is_add = _batch(rng, busy, 200)
-    got = _launch(ex, pool, rows, m, k, keys, is_add, hashed)
-    np.testing.assert_array_equal(got, ref.run(rows, keys, is_add))
+    got = _launch(ex, pool, rows, m, k, keys, is_add, shape)
+    np.testing.assert_array_equal(got, ref.run(rows, keys, is_add, shape))
     # The idle shard's filters saw no add: every key reads absent.
     probe = np.arange(60, dtype=np.uint64)
     prow = np.resize(idle, probe.shape[0]).astype(np.int32)
-    got = _launch(ex, pool, prow, m, k, probe, np.zeros(60, bool), hashed)
+    got = _launch(ex, pool, prow, m, k, probe, np.zeros(60, bool), shape)
     assert not got.any()
 
 
-@pytest.mark.parametrize("hashed", [False, True],
-                         ids=["bloom_mixed_keys", "bloom_mixed"])
-def test_busiest_shard_crosses_a_bucket(sharded, placed, hashed):
+@pytest.mark.parametrize("shape", ["u64", "str"])
+def test_busiest_shard_crosses_a_bucket(sharded, placed, shape):
     """One shard takes more ops than the smallest bucket holds, so the
     block's Bp steps up while the other shards stay nearly empty."""
     pool, trows, m, k = _tenants(sharded)
@@ -163,43 +166,43 @@ def test_busiest_shard_crosses_a_bucket(sharded, placed, hashed):
         rng.shuffle(rows)
         keys = rng.integers(0, 80, size=rows.shape[0], dtype=np.uint64)
         is_add = rng.random(rows.shape[0]) < 0.4
-        got = _launch(ex, pool, rows, m, k, keys, is_add, hashed)
-        np.testing.assert_array_equal(got, ref.run(rows, keys, is_add))
+        got = _launch(ex, pool, rows, m, k, keys, is_add, shape)
+        np.testing.assert_array_equal(got, ref.run(rows, keys, is_add, shape))
     bps = [block.shape[2] for block in placed]
     assert bps == [small, ex._bucket(small + 10), ex._bucket(3 * small)]
     assert bps[0] < bps[1] < bps[2]
 
 
-@pytest.mark.parametrize("hashed", [False, True],
-                         ids=["bloom_mixed_keys", "bloom_mixed"])
-def test_one_block_placed_with_state_sharding(sharded, placed, hashed):
+@pytest.mark.parametrize("shape", ["u64", "str"])
+def test_one_block_placed_with_state_sharding(sharded, placed, shape):
     pool, trows, m, k = _tenants(sharded)
     ex = sharded._engine.executor
     rows, keys, is_add = _batch(np.random.default_rng(1), trows, 150)
-    _launch(ex, pool, rows, m, k, keys, is_add, hashed)
+    _launch(ex, pool, rows, m, k, keys, is_add, shape)
     assert len(placed) == 1
     (block,) = placed
     assert block.sharding == ex.ctx.state_sharding
     assert block.committed
-    # h1m, h2m | the key length and the key lanes left after trimming.
-    lanes = ex._trim_lanes(hashing.encode_uint64_batch(keys)[0])[0]
-    payload = 2 if hashed else 1 + lanes.shape[1]
+    # The key length and the key lanes left after trimming.
+    lanes = ex._trim_lanes(_encode(keys, shape)[0])[0]
+    payload = 1 + lanes.shape[1]
+    # String keys span more than one 16-byte block; 8-byte keys do not.
+    assert (lanes.shape[1] > 4) == (shape == "str")
     Bp = ex._bucket(int(np.bincount(rows % S).max()))
     assert block.shape == (S, pm.BLOOM_HEAD + payload, Bp)
     assert str(block.dtype) == "uint32"
 
 
-@pytest.mark.parametrize("method,hashed", [("bloom_mixed_keys", False),
-                                           ("bloom_mixed", True)])
-def test_h2d_puts_one_per_bloom_launch(sharded, method, hashed):
+@pytest.mark.parametrize("shape", ["u64", "str"])
+def test_h2d_puts_one_per_bloom_launch(sharded, shape):
     pool, trows, m, k = _tenants(sharded)
     ex = sharded._engine.executor
     obs = sharded._engine.obs
     rng = np.random.default_rng(2)
     for i in range(1, 4):
         rows, keys, is_add = _batch(rng, trows, 100)
-        _launch(ex, pool, rows, m, k, keys, is_add, hashed)
-        assert obs.h2d_puts.get((method,)) == i
+        _launch(ex, pool, rows, m, k, keys, is_add, shape)
+        assert obs.h2d_puts.get(("bloom_mixed_keys",)) == i
 
 
 def test_other_partitioned_kinds_place_with_state_sharding(sharded, placed):
@@ -235,7 +238,7 @@ def test_not_fully_addressable_mesh_keeps_default_device_put(monkeypatch,
         pool, trows, m, k = _tenants(cl)
         ref = _Golden(trows, m, k)
         rows, keys, is_add = _batch(np.random.default_rng(9), trows, 200)
-        got = _launch(ex, pool, rows, m, k, keys, is_add, False)
+        got = _launch(ex, pool, rows, m, k, keys, is_add)
         np.testing.assert_array_equal(got, ref.run(rows, keys, is_add))
         assert len(placed) == 1 and not placed[0].committed
     finally:
@@ -262,7 +265,7 @@ def test_one_chip_puts_stay_uncommitted(monkeypatch):
         monkeypatch.setattr(tpu_executor, "_put_staged", spy)
         ref = _Golden(trows, m, k)
         rows, keys, is_add = _batch(np.random.default_rng(4), trows, 100)
-        got = _launch(ex, pool, rows, m, k, keys, is_add, False)
+        got = _launch(ex, pool, rows, m, k, keys, is_add)
         np.testing.assert_array_equal(got, ref.run(rows, keys, is_add))
         assert seen == [None]
         assert cl._engine.obs.h2d_puts.get(("bloom_mixed_keys",)) == 1

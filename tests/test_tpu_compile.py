@@ -114,34 +114,60 @@ def _u32(n, hi, seed=0):
 
 @pytest.mark.parametrize("op", ["bloom_add", "bloom_contains"])
 def test_bloom_config1_row(ex, op):
-    """Config 1: one 1M-key 1% filter (m=9,585,059), 1M-op launch."""
+    """Config 1: one 1M-key 1% filter (m=9,585,059), a 1M-op direct
+    launch: the keyed add (bloom_mixed_keys, all flags set) or the
+    single-row contains (bloom_contains_keys_st)."""
     m, B = 9_585_059, 1 << 20
     pool = _pool(ex, PoolKind.BLOOM, (class_words_for_bits(m),))
-    rows = np.zeros(B, np.int32)
-    m_arr = np.full(B, m, np.uint32)
-    c = _compile(lambda: getattr(ex, op)(
-        pool, rows, m_arr, K, _u32(B, m), _u32(B, m, 1)))
+    blocks = np.zeros((B, 4), np.uint32)  # LongCodec: 8-byte keys
+    blocks[:, :2] = np.random.default_rng(0).integers(0, 1 << 32, (B, 2))
+    lengths = np.full(B, 8, np.uint32)
+    if op == "bloom_add":
+        call = lambda: ex.bloom_mixed_keys(  # noqa: E731
+            pool, np.zeros(B, np.int32), np.full(B, m, np.uint32), K,
+            blocks, lengths, np.ones(B, bool))
+    else:
+        call = lambda: ex.bloom_contains_keys_st(  # noqa: E731
+            pool, 0, m, K, blocks, lengths)
+    c = _compile(call)
     assert c.memory_analysis().argument_size_in_bytes >= 4 * pool.row_units
+
+
+def test_bloom_direct_add_past_scan_chunk(ex):
+    """A 3M-key direct add on the config-1 filter: bloom_mixed_keys
+    scans _SCAN_CHUNK-op chunks in order, so the device hash's
+    intermediates fit HBM."""
+    from redisson_tpu.executor.tpu_executor import _SCAN_CHUNK
+
+    m, B = 9_585_059, 3 * _SCAN_CHUNK - 5
+    pool = _pool(ex, PoolKind.BLOOM, (class_words_for_bits(m),))
+    blocks = np.zeros((B, 4), np.uint32)
+    blocks[:, :2] = np.random.default_rng(1).integers(0, 1 << 32, (B, 2))
+    c = _compile(lambda: ex.bloom_mixed_keys(
+        pool, np.zeros(B, np.int32), np.full(B, m, np.uint32), K,
+        blocks, np.full(B, 8, np.uint32), np.ones(B, bool)))
+    assert c.memory_analysis().temp_size_in_bytes < 8 << 30
 
 
 @pytest.mark.parametrize("op", ["bloom_mixed", "bloom_mixed_keys_runs",
                                 "bloom_contains_keys_runs"])
 def test_bloom_config4_stacked_pool(ex, op):
     """Config 4: 1000 stacked 10k/1% tenants (pool grown to 1024 rows),
-    one 64k-op segment — host-hashed and device-hashed mixed forms, and
-    the contains-only gather."""
+    one 64k-op segment — the per-op keyed mix (``bloom_mixed``:
+    bloom_mixed_keys, the replica and mixed-flag form), the run-length
+    scan, and the contains-only gather."""
     m, B, T = 95_851, 1 << 16, 1024
     pool = _pool(ex, PoolKind.BLOOM, (class_words_for_bits(m),), tenants=T)
     rng = np.random.default_rng(0)
+    blocks = np.zeros((B, 4), np.uint32)  # LongCodec: 8-byte keys
+    blocks[:, :2] = rng.integers(0, 1 << 32, (B, 2))
     if op == "bloom_mixed":
-        call = lambda: ex.bloom_mixed(  # noqa: E731
+        call = lambda: ex.bloom_mixed_keys(  # noqa: E731
             pool, rng.integers(0, 1000, B).astype(np.int32),
-            np.full(B, m, np.uint32), K, _u32(B, m), _u32(B, m, 1),
+            np.full(B, m, np.uint32), K, blocks, np.full(B, 8, np.uint32),
             rng.random(B) < 0.3)
     else:
         C = B // 256  # one run per submitted 256-key chunk
-        blocks = np.zeros((B, 4), np.uint32)  # LongCodec: 8-byte keys
-        blocks[:, :2] = rng.integers(0, 1 << 32, (B, 2))
         runs = (rng.integers(0, 1000, C).astype(np.int32),
                 np.full(C, m, np.uint32))
         flags = (rng.random(C) < 0.3,) if op == "bloom_mixed_keys_runs" else ()

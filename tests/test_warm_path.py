@@ -21,6 +21,7 @@ import redisson_tpu
 from redisson_tpu import Config
 from redisson_tpu.codecs import LongCodec
 from redisson_tpu.executor import prewarm
+from redisson_tpu.utils import hashing
 
 
 def _client(**kw):
@@ -34,16 +35,20 @@ def _client(**kw):
 
 
 def test_prewarm_populates_every_bucket():
+    """The first encoded submit schedules the per-op keyed ladder
+    (bloom_mixed_keys, the replica and mixed-flag launch) at every
+    bucket; the submit itself runs the run-length path."""
     cl = _client(prewarm=True, max_batch=2048)
     try:
         bf = cl.get_bloom_filter("pw-bf")
         bf.try_init(10_000, 0.01)
+        bf.add_all(np.arange(100, dtype=np.uint64))
         assert cl.prewarm_wait(300), "pre-warm did not drain"
         ex = cl._engine.executor
         pw = cl._engine.prewarmer
         ladder = pw.ladder()
         assert ladder[-1] >= 2048 and len(ladder) >= 4
-        cached = {k[3] for k in ex._jit_cache if k[0] == "bloom_mixed"}
+        cached = {k[3] for k in ex._jit_cache if k[0] == "bloom_mixed_keys"}
         assert cached == set(ladder), (sorted(cached), ladder)
         assert pw.errors == 0
     finally:
@@ -84,6 +89,11 @@ def test_prewarm_reruns_on_pool_growth():
             bf = cl.get_bloom_filter(f"grow-{i}")
             bf.try_init(1000, 0.01)
             filters.append(bf)
+            if i == 0:
+                # The first submit registers the pool's keyed ladders;
+                # the third tenant's growth must re-run them.
+                bf.add_all(np.arange(10, dtype=np.uint64))
+                assert cl.prewarm_wait(300)
         assert cl.prewarm_wait(300)
         ex = cl._engine.executor
         pool = cl._engine.registry.lookup("grow-0").pool
@@ -91,10 +101,10 @@ def test_prewarm_reruns_on_pool_growth():
         state_len = pool.state.shape[0]
         cached = {
             k[3] for k in ex._jit_cache
-            if k[0] == "bloom_mixed" and k[2] == state_len
+            if k[0] == "bloom_mixed_keys" and k[2] == state_len
         }
         assert cached == set(cl._engine.prewarmer.ladder()), (
-            sorted(k for k in ex._jit_cache if k[0] == "bloom_mixed"),
+            sorted(k for k in ex._jit_cache if k[0] == "bloom_mixed_keys"),
             state_len,
             cl._engine.prewarmer.errors,
         )
@@ -129,7 +139,7 @@ def test_staging_buffers_reused_across_flushes():
     (ring reuse) — no per-flush allocation growth.  Exercised on the
     executor directly (the rings are thread-local, so the test thread
     dispatching directly observes its own ring)."""
-    cl = _client(coalesce=False, exact_add_semantics=True)
+    cl = _client(coalesce=False)
     try:
         bf = cl.get_bloom_filter("stage-bf")
         bf.try_init(10_000, 0.01)
@@ -143,13 +153,16 @@ def test_staging_buffers_reused_across_flushes():
             B = 64
             rows = np.full(B, row, np.int32)
             m_arr = np.full(B, m, np.uint32)
-            h = rng.integers(0, m, B).astype(np.uint32)
-            ex.bloom_mixed(pool, rows, m_arr, k, h, h, np.zeros(B, bool))
+            keys = rng.integers(1 << 40, 1 << 50, B).astype(np.uint64)
+            blocks, lengths = hashing.encode_uint64_batch(keys)
+            ex.bloom_mixed_keys(pool, rows, m_arr, k, blocks, lengths,
+                                np.zeros(B, bool))
 
         for _ in range(10):  # > ring depth: every slot allocated
             dispatch_once()
         rings = ex._staging.rings  # this thread's rings
-        key = ("bloom_mixed", ex._bucket(64))
+        # Keys of 2 lanes in use (Lt = 2) after trimming.
+        key = ("bloom_mixed_keys", ex._bucket(64), 2)
         assert key in rings
         bufs_before = {id(s.buf) for s in rings[key][1]}
         assert len(bufs_before) <= 8  # bounded by ring depth
