@@ -41,6 +41,7 @@ import jax.numpy as jnp
 from redisson_tpu import chaos as _chaos
 from redisson_tpu.ops import bitops
 from redisson_tpu.ops import bitset as bitset_ops
+from redisson_tpu.ops import bloom as bloom_ops
 from redisson_tpu.ops import golden
 from redisson_tpu.executor.tpu_executor import (
     DISPATCH_METHODS,
@@ -316,6 +317,9 @@ class ShardedTpuCommandExecutor(TpuCommandExecutor):
             lengths = np.full(len(rows), lengths, np.uint32)
         pool.state, packed = fn(pool.state, self._bloom_put(
             p, m_arr, is_add, lengths, blocks_t))
+        # Each shard scans its own [Bp] slice; the count is one shard's.
+        self._count_scan("bloom_mixed_keys", bloom_ops.scan_steps(p.Bp, k),
+                         pool.state.shape[1])
         return LazyResult(packed, transform=p.unpack_bools)
 
     def bloom_contains_keys_st(self, pool, row: int, m: int, k: int, blocks, lengths) -> LazyResult:

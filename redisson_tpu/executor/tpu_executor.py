@@ -642,6 +642,13 @@ class TpuCommandExecutor:
 
     # -- bloom -------------------------------------------------------------
 
+    def _count_scan(self, method: str, steps: int, state_len: int) -> None:
+        """``rtpu_bloom_scan_steps``: the steps of the add scan a launch
+        runs (its static shape, nothing read back from the device)."""
+        if self.obs is not None:
+            carry = "lanes" if bitops.lane_fits(state_len) else "flat"
+            self.obs.bloom_scan_steps.inc((method, carry), steps)
+
     def bloom_mixed_keys(self, pool, rows, m_arr, k: int, blocks, lengths, is_add) -> LazyResult:
         """Combined add+contains from raw codec lanes with exact
         sequential semantics — device-side murmur + 64-bit mod
@@ -718,6 +725,9 @@ class TpuCommandExecutor:
         o = _fill_bits(buf, o, Bp, is_add)
         _fill_blocks(buf, o, Bp, blocks)
         pool.state, res = fn(pool.state, self._ship(slot, total))
+        self._count_scan("bloom_mixed_keys",
+                         Bp // chunk * bloom_ops.scan_steps(chunk, k),
+                         pool.state.shape[0])
         return LazyResult(res, transform=lambda v: bitops.unpack_bool_u32(v, B))
 
     def bloom_mixed_keys_runs(self, pool, k: int, blocks, lengths, run_rows, run_m, run_flags, run_starts) -> LazyResult:
@@ -835,6 +845,8 @@ class TpuCommandExecutor:
         res = fn(pool.state, self._ship(slot, total))
         if writes:
             pool.state, res = res
+            self._count_scan("bloom_mixed_keys_runs",
+                             bloom_ops.scan_steps(Bp, k), pool.state.shape[0])
         return LazyResult(res, transform=lambda v: bitops.unpack_bool_u32(v, B))
 
     def bitset_mixed_runs(self, pool, idx, run_rows, run_ops, run_starts) -> LazyResult:

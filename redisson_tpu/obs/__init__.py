@@ -110,6 +110,12 @@ class Observability:
             "rtpu_h2d_puts",
             "host-to-device transfers (device_put calls) the executor's "
             "staging helpers make, by executor method", ("method",))
+        self.bloom_scan_steps = r.counter(
+            "rtpu_bloom_scan_steps",
+            "steps of the Bloom add scan the executor launched, from each "
+            "launch's static shape, by executor method and the pool form "
+            "the scan carries (lanes: the 128-lane view; flat: the "
+            "fallback)", ("method", "carry"))
         self.compiles = r.counter(
             "rtpu_compiles",
             "XLA backend compiles, by the executor method dispatching "

@@ -27,7 +27,10 @@ elements would in one stable sort.
 State convention: a pool of T tenant rows × W words lives as a flat
 ``uint32[T*W + 1]`` array; the trailing word is a scratch slot that padded
 (invalid) ops target, so padding never perturbs run-detection for real ops
-and scatters to it are harmless.
+and scatters to it are harmless.  The Bloom set kernels also take the
+pool's ``lane_view`` (``[T*W/128, 128]``, scratch held aside): a scan
+carries that view from step to step, reading and writing it by 128-lane
+rows, and rebuilds the flat array once at the end (ops/bloom._scan_ops).
 
 All functions here are pure and jittable; the executor layer applies
 ``jax.jit`` with buffer donation.
@@ -146,6 +149,19 @@ def lane_view(flat: jnp.ndarray):
     return flat[:-1].reshape(n // 128, 128)
 
 
+def lane_fits(flat_len: int) -> bool:
+    """Whether a flat pool of ``flat_len`` words has a lane view: its
+    words less the scratch fill whole 128-lane rows (every registry
+    pool, see tenancy/registry.class_words_for_bits)."""
+    return (flat_len - 1) % 128 == 0
+
+
+def scratch_index(words: jnp.ndarray) -> int:
+    """Word index of the scratch slot padded ops target: the flat pool's
+    trailing element, or one past the last row of its lane view."""
+    return words.size if words.ndim == 2 else words.shape[0] - 1
+
+
 def gather_words(flat: jnp.ndarray, gidx: jnp.ndarray):
     """Element gather from a flat pool array via the [R, 128] row-gather
     form (see gather_bits).  Works for any dtype; exact equivalent of
@@ -153,7 +169,7 @@ def gather_words(flat: jnp.ndarray, gidx: jnp.ndarray):
     pool's ``lane_view``."""
     if flat.ndim == 2:
         x2d = flat
-    elif (flat.shape[0] - 1) % 128 != 0:
+    elif not lane_fits(flat.shape[0]):
         return flat[gidx]
     else:
         x2d = lane_view(flat)
@@ -255,9 +271,9 @@ def gather_bits(flat_words: jnp.ndarray, gword: jnp.ndarray, bit: jnp.ndarray):
     by a one-hot lane compare.  Exactly equivalent to flat_words[gword].
 
     Pool states keep (len-1) % 128 == 0 (registry classes are 128-word
-    multiples); padded ops routed to the scratch word read out of range and
-    are clipped by jnp.take's default clamping — their results are masked
-    by the caller.
+    multiples); padded ops routed to the scratch word read out of range
+    (jnp.take fills such a row with ones) — their results are masked by
+    the caller.
     """
     return (gather_words(flat_words, gword) >> bit) & _ONE
 
@@ -269,16 +285,38 @@ def scatter_set_bits(flat_words, gword, bit):
     set pre-batch OR an earlier op in the batch set it.
     """
     return scan_chunks(_set_bits_chunk, flat_words, (gword, bit),
-                       (flat_words.shape[0] - 1, 0))
+                       (scratch_index(flat_words), 0))
 
 
-def _set_bits_chunk(flat_words, gword, bit):
+def _set_bits_chunk(words, gword, bit):
     sw, sb, sp, first, _ = sort_runs(gword, bit)
-    pre = gather_bits(flat_words, sw, sb)
-    new = flat_words.at[sw].add(_signed_masks(sb, first & (pre == 0), False))
-    prev_sorted = jnp.where(first, pre, _ONE)
-    prev = jnp.zeros_like(prev_sorted).at[sp].set(prev_sorted)
-    return new, prev
+    pre = gather_bits(words, sw, sb)
+    new = _add_sorted(words, sw, _signed_masks(sb, first & (pre == 0), False))
+    return new, _unsort(sp, jnp.where(first, pre, _ONE))
+
+
+def _add_sorted(words, sw, masks):
+    """``words[sw] += masks`` for word indexes ``sw`` in ascending order.
+    A flat pool takes an element scatter.  A lane view takes a 128-lane
+    one-hot row scatter-add, in place in a scan's carry: exact because
+    each (word, bit) gets at most one nonzero mask, and distinct bits of
+    one word sum to their OR.  The scratch index, one past the view,
+    adds a zero row to the last row."""
+    if words.ndim == 1:
+        return words.at[sw].add(masks)
+    real = sw < np.uint32(words.size)
+    row = jnp.minimum(sw >> _U7, np.uint32(words.shape[0] - 1))
+    lane = (sw & _U127).astype(jnp.int32)
+    onehot = (jnp.arange(128, dtype=jnp.int32)[None, :] == lane[:, None]) \
+        & real[:, None]
+    upd = jnp.where(onehot, masks[:, None], np.uint32(0))
+    return words.at[row.astype(jnp.int32)].add(upd, indices_are_sorted=True)
+
+
+def _unsort(sp, vals):
+    """``vals`` of sorted positions back in arrival order (``sp`` is a
+    permutation): a sort keyed by ``sp``, not an element scatter."""
+    return lax.sort((sp, vals), num_keys=1)[1]
 
 
 def scatter_set_bits_masked(flat_words, gword, bit, is_write):
@@ -294,11 +332,11 @@ def scatter_set_bits_masked(flat_words, gword, bit, is_write):
     return scan_chunks(
         _set_bits_masked_chunk, flat_words,
         (gword, bit, is_write.astype(jnp.int32)),
-        (flat_words.shape[0] - 1, 0, 0),
+        (scratch_index(flat_words), 0, 0),
     )
 
 
-def _set_bits_masked_chunk(flat_words, gword, bit, wr):
+def _set_bits_masked_chunk(words, gword, bit, wr):
     n = gword.shape[0]
     pos = jnp.arange(n, dtype=jnp.int32)
     sw, sb, sp, swr = lax.sort((gword, bit, pos, wr), num_keys=2, is_stable=True)
@@ -308,12 +346,11 @@ def _set_bits_masked_chunk(flat_words, gword, bit, wr):
     # Earlier writer exists in this run <=> exclusive segmented max of
     # (pos+1 for writers, 0 for readers) is nonzero.
     earlier_writer = segmented_exclusive_max(first, swr * (sp + 1)) > 0
-    pre = gather_bits(flat_words, sw, sb)
+    pre = gather_bits(words, sw, sb)
     obs_sorted = pre | earlier_writer.astype(jnp.uint32)
     contributes = (swr > 0) & ~earlier_writer & (pre == 0)
-    new = flat_words.at[sw].add(_signed_masks(sb, contributes, False))
-    obs = jnp.zeros_like(obs_sorted).at[sp].set(obs_sorted)
-    return new, obs
+    new = _add_sorted(words, sw, _signed_masks(sb, contributes, False))
+    return new, _unsort(sp, obs_sorted)
 
 
 def _segmented_affine_scan(first, b, a):

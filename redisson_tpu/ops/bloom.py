@@ -10,6 +10,11 @@ Pool layout: ``uint32[T*W + 1]`` flat words (see ops/bitops.py), all
 tenants in one size class share (m, W); per-op tenant rows route each key.
 ``k`` (hash iterations) is static per launch — the coalescer groups ops by
 (size class, k).
+
+The set kernels scan chunks of ops in order (``_scan_ops``).  The scan
+carries the pool as its ``[T*W/128, 128]`` lane view, which each step
+reads and writes by 128-lane rows: the flat array is sliced once before
+the scan and rebuilt once after it, never relaid inside a step.
 """
 
 from __future__ import annotations
@@ -43,33 +48,52 @@ def bloom_add(flat_words, rows, h1m, h2m, *, m: int, k: int, words_per_row: int,
     by all earlier keys in the batch.  ``valid``: optional bool[B] padding
     mask — invalid ops are routed to the scratch word and write nothing.
     """
-    def kernel(flat, rows, h1m, h2m, valid, m):
+    def kernel(words, rows, h1m, h2m, valid, m):
         idx = bitops.expand_km_indexes(h1m, h2m, m, k)
         gword, bit = _op_words(rows[:, None], idx, words_per_row)
         gword = bitops.route_invalid_to_scratch(
-            gword, valid[:, None], flat.shape[0])
+            gword, valid[:, None], bitops.scratch_index(words) + 1)
         new, prev = bitops.scatter_set_bits(
-            flat, gword.reshape(-1), bit.reshape(-1))
+            words, gword.reshape(-1), bit.reshape(-1))
         return new, (prev == 0).reshape(idx.shape).any(axis=1)
 
     return _scan_ops(kernel, flat_words, k, m, valid, rows, h1m, h2m)
 
 
+def scan_chunk_ops(k: int) -> int:
+    """Ops a step of ``_scan_ops`` takes: the largest power of two whose
+    k-fold expansion fits one ``bitops.SORT_CHUNK`` sort."""
+    return 1 << max(0, (bitops.SORT_CHUNK // k).bit_length() - 1)
+
+
+def scan_steps(n_ops: int, k: int) -> int:
+    """Steps ``_scan_ops`` runs for a batch of ``n_ops`` ops."""
+    return -(-n_ops // scan_chunk_ops(k))
+
+
 def _scan_ops(kernel, flat_words, k: int, m, valid, *cols):
     """bitops.scan_chunks over OPS (each expands to k sorted elements):
-    ``kernel(flat, *cols, valid, m)`` sees one chunk of at most
-    SORT_CHUNK // k ops.  Padding ops are invalid (scratch word, m=1)."""
+    ``kernel(words, *cols, valid, m)`` sees one chunk of at most
+    ``scan_chunk_ops(k)`` ops.  Padding ops are invalid (scratch word,
+    m=1).  ``words`` is the pool's lane view wherever it has one
+    (``bitops.lane_fits``), else the flat array."""
     B = cols[0].shape[0]
     if valid is None:
         valid = jnp.ones((B,), bool)
-    chunk = 1 << max(0, (bitops.SORT_CHUNK // k).bit_length() - 1)
+    chunk = scan_chunk_ops(k)
+    lanes = bitops.lane_fits(flat_words.shape[0])
+    words = bitops.lane_view(flat_words) if lanes else flat_words
     if isinstance(m, (int, np.integer)):  # static m stays static
-        return bitops.scan_chunks(
-            lambda f, *c: kernel(f, *c, m), flat_words,
+        new, out = bitops.scan_chunks(
+            lambda f, *c: kernel(f, *c, m), words,
             cols + (valid,), (0,) * len(cols) + (False,), chunk)
-    return bitops.scan_chunks(
-        kernel, flat_words, cols + (valid, m),
-        (0,) * len(cols) + (False, 1), chunk)
+    else:
+        new, out = bitops.scan_chunks(
+            kernel, words, cols + (valid, m),
+            (0,) * len(cols) + (False, 1), chunk)
+    if lanes:
+        new = jnp.concatenate([new.reshape(-1), flat_words[-1:]])
+    return new, out
 
 
 def bloom_mixed(flat_words, rows, h1m, h2m, is_add, *, m, k: int, words_per_row: int, valid=None):
@@ -85,14 +109,14 @@ def bloom_mixed(flat_words, rows, h1m, h2m, is_add, *, m, k: int, words_per_row:
     breaking a new segment on every add/contains alternation.
     Returns (new_flat, result bool[B]).
     """
-    def kernel(flat, rows, h1m, h2m, is_add, valid, m):
+    def kernel(words, rows, h1m, h2m, is_add, valid, m):
         idx = bitops.expand_km_indexes(h1m, h2m, m, k)
         gword, bit = _op_words(rows[:, None], idx, words_per_row)
         gword = bitops.route_invalid_to_scratch(
-            gword, valid[:, None], flat.shape[0])
+            gword, valid[:, None], bitops.scratch_index(words) + 1)
         wr = jnp.broadcast_to(is_add[:, None], idx.shape).reshape(-1)
         new, obs = bitops.scatter_set_bits_masked(
-            flat, gword.reshape(-1), bit.reshape(-1), wr)
+            words, gword.reshape(-1), bit.reshape(-1), wr)
         all_set = (obs == 1).reshape(idx.shape).all(axis=1)
         return new, jnp.where(is_add, ~all_set, all_set)
 

@@ -122,10 +122,17 @@ def _dispatch_ops(client) -> dict:
     return {labels[0]: int(c.value) for labels, c in fam.items()}
 
 
+def _scan_steps(client) -> dict:
+    fam = client._engine.obs.bloom_scan_steps
+    return {labels: int(c.value) for labels, c in fam.items()}
+
+
 def test_routing_by_add_flags():
     """A segment with no add run lands on the gather and is counted under
     its method; one add run keeps the whole segment on the scan, whose
-    sequential semantics let a contains see an add queued before it."""
+    sequential semantics let a contains see an add queued before it.
+    Only the scan counts ``rtpu_bloom_scan_steps``, one step for a
+    128-op launch, carrying the pool's lane view."""
     c = _tpu(LongCodec())
     try:
         bf = _fill(c, LongCodec())["g0"]
@@ -143,7 +150,7 @@ def test_routing_by_add_flags():
                      for keys, flag in keys_flags]
             return dispatch(cols, metas).result()
 
-        before = _dispatch_ops(c)
+        before, steps = _dispatch_ops(c), _scan_steps(c)
         fresh = np.arange(50_000, 50_064, dtype=np.uint64)
         got = launch([(np.arange(0, 64, dtype=np.uint64), False),
                       (fresh, False)])
@@ -153,6 +160,7 @@ def test_routing_by_add_flags():
             "bloom_contains_keys_runs", 0) == 128
         assert after.get("bloom_mixed_keys_runs", 0) == before.get(
             "bloom_mixed_keys_runs", 0)
+        assert _scan_steps(c) == steps
         # add(fresh) then contains(fresh) in ONE segment: the scan.
         got = launch([(fresh, True), (fresh, False)])
         final = _dispatch_ops(c)
@@ -161,6 +169,8 @@ def test_routing_by_add_flags():
             "bloom_mixed_keys_runs", 0) == 128
         assert final["bloom_contains_keys_runs"] == after[
             "bloom_contains_keys_runs"]
+        lanes = ("bloom_mixed_keys_runs", "lanes")
+        assert _scan_steps(c) == {**steps, lanes: steps.get(lanes, 0) + 1}
         assert bf.contains_all(fresh) == 64
     finally:
         c.shutdown()

@@ -115,6 +115,69 @@ class TestBloomMixed:
             np.asarray(res)[:n], np.asarray(newly)
         )
 
+    @pytest.mark.parametrize("words_per_row", [512, 100],
+                             ids=["lanes", "flat"])
+    def test_chunked_scan_vs_golden(self, monkeypatch, words_per_row):
+        """A launch of 40 steps (SORT_CHUNK shrunk to 64 elements: 8 ops
+        a step) against the golden model op by op, on a pool that has a
+        lane view (512-word rows: the scan carries the view) and on one
+        that has none (100-word rows: the flat fallback).  Three tenants
+        of different m; a key repeated across every step boundary, so
+        duplicate (word, bit) runs straddle chunks; an add and a later
+        contains of one key 30 steps apart; 20 padding ops with real
+        rows and m, half adds, routed to scratch.  Every real word is
+        checked afterwards, bits past each tenant's m included, and on
+        the lane view the scratch word too (the flat fallback may set
+        bits there)."""
+        monkeypatch.setattr(bitops, "SORT_CHUNK", 64)
+        T, W, K, n, Bp = 3, words_per_row, self.K, 300, 320
+        step = bloom.scan_chunk_ops(K)
+        assert step == 8 and bloom.scan_steps(Bp, K) == 40
+        assert bitops.lane_fits(T * W + 1) == (W == 512)
+        ms = [W * 32, W * 32 * 5 // 9 + 1, W * 32 // 3 + 7]
+        rng = np.random.default_rng(31)
+        rows = rng.integers(0, T, Bp).astype(np.int32)
+        keys = rng.integers(0, 30, Bp, dtype=np.uint64)
+        is_add = rng.random(Bp) < 0.5
+        for j in range(step, n, step):  # same key either side of a boundary
+            rows[j], keys[j], is_add[j - 1] = rows[j - 1], keys[j - 1], True
+        rows[[3, 243]], keys[[3, 243]] = 0, 1000
+        is_add[[3, 243]] = True, False
+        valid = np.arange(Bp) < n
+        blocks, lengths = hashing.encode_uint64_batch(keys)
+        h1, h2 = hashing.hash128_np(blocks, lengths)
+        h1m, h2m = np.zeros(Bp, np.uint32), np.zeros(Bp, np.uint32)
+        for t, m in enumerate(ms):
+            sel = rows == t
+            h1m[sel], h2m[sel] = hashing.km_reduce_mod(h1[sel], h2[sel], m)
+        draw = lambda: rng.integers(0, 1 << 32, T * W + 1,  # noqa: E731
+                                    dtype=np.uint64)
+        words = (draw() & draw() & draw()).astype(np.uint32)  # 1 bit in 8
+        words[-1] = 0xDEADBEEF
+        bits = np.unpackbits(words[:-1].view(np.uint8),
+                             bitorder="little").reshape(T, W * 32)
+        g = []
+        for t, m in enumerate(ms):
+            g.append(golden.GoldenBloomFilter(m, K))
+            g[-1].bits = bits[t, :m].astype(bool)
+        want = self._golden_run(g, rows[:n], h1m, h2m, is_add)
+        assert want[243]  # the contains sees the add 30 steps before it
+        new, res = bloom.bloom_mixed(
+            jnp.asarray(words), jnp.asarray(rows), jnp.asarray(h1m),
+            jnp.asarray(h2m), jnp.asarray(is_add),
+            m=jnp.asarray(np.array(ms, np.uint32)[rows]), k=K,
+            words_per_row=W, valid=jnp.asarray(valid))
+        np.testing.assert_array_equal(np.asarray(res)[:n], want)
+        for t, m in enumerate(ms):
+            bits[t, :m] = g[t].bits
+        want_words = np.packbits(bits.reshape(-1), bitorder="little").view(
+            np.uint32)
+        new = np.asarray(new)
+        assert new.shape == words.shape
+        np.testing.assert_array_equal(new[:-1], want_words)
+        if W == 512:
+            assert new[-1] == 0xDEADBEEF
+
 
 class TestBitsetMixed:
     W = 8  # 256-bit rows

@@ -19,7 +19,7 @@ import redisson_tpu
 from redisson_tpu import Config
 from redisson_tpu.codecs import LongCodec
 from redisson_tpu.executor import sharded_executor, tpu_executor
-from redisson_tpu.ops import golden
+from redisson_tpu.ops import bloom, golden
 from redisson_tpu.parallel import mesh as pm
 from redisson_tpu.tenancy import PoolKind
 from redisson_tpu.utils import hashing
@@ -114,18 +114,29 @@ def _batch(rng, tenant_rows, n, key_space=60):
     return rows, keys, rng.random(n) < 0.4
 
 
-@pytest.mark.parametrize("shape", ["u64", "str"])
-def test_packed_launch_matches_golden(sharded, shape):
+@pytest.mark.parametrize("shape,n_ops", [
+    ("u64", 300), ("str", 300), ("u64", 4000), ("str", 4000)],
+    ids=["u64", "str", "u64-scan", "str-scan"])
+def test_packed_launch_matches_golden(sharded, shape, n_ops):
     """Mixed add and contains over many tenants, duplicates in a batch,
-    several batches on the same state."""
+    several batches on the same state.  The ``-scan`` cases put more
+    than 1,024 ops on the busiest shard, so each shard's add scan runs
+    several steps carrying its pool's lane view."""
     pool, trows, m, k = _tenants(sharded)
     ex = sharded._engine.executor
     ref = _Golden(trows, m, k)
     rng = np.random.default_rng(7)
-    for _ in range(4):
-        rows, keys, is_add = _batch(rng, trows, 300)
+    steps = 0
+    for _ in range(4 if n_ops < 1000 else 2):
+        rows, keys, is_add = _batch(rng, trows, n_ops)
         got = _launch(ex, pool, rows, m, k, keys, is_add, shape)
         np.testing.assert_array_equal(got, ref.run(rows, keys, is_add, shape))
+        busiest = int(np.bincount(rows % S).max())
+        assert (busiest > bloom.scan_chunk_ops(k)) == (n_ops > 1000)
+        steps += bloom.scan_steps(ex._bucket(busiest), k)
+    counted = sharded._engine.obs.bloom_scan_steps
+    assert counted.get(("bloom_mixed_keys", "lanes")) == steps
+    assert counted.get(("bloom_mixed_keys", "flat")) == 0
 
 
 @pytest.mark.parametrize("shape", ["u64", "str"])

@@ -1,7 +1,9 @@
 """Chunked exact-sequential kernels (bitops.scan_chunks, PR 21): a batch
 longer than SORT_CHUNK elements runs as an in-order scan over chunks and
 must give exactly what one-op-at-a-time execution gives — duplicates
-straddling chunk boundaries and a padded tail included."""
+straddling chunk boundaries and a padded tail included.  The set kernels
+give the same on the pool's lane view, the form the Bloom scan carries
+(``-lanes`` cases)."""
 
 import numpy as np
 import pytest
@@ -35,9 +37,11 @@ def _sequential(flat, gword, bit, step):
     return flat, obs
 
 
-@pytest.mark.parametrize("kind", ["set", "clear", "flip", "masked", "affine"])
+@pytest.mark.parametrize("kind", ["set", "clear", "flip", "masked", "affine",
+                                  "set-lanes", "masked-lanes"])
 def test_chunked_bit_kernels_match_sequential(kind):
-    rng, flat, gword, bit = _ops(len(kind))
+    kind, lanes = kind.removesuffix("-lanes"), kind.endswith("-lanes")
+    rng, flat, gword, bit = _ops(len(kind) + 7 * lanes)
     write = rng.random(N) < 0.5
     opcodes = rng.integers(0, 4, N).astype(np.uint32)
     b_coef, a_coef = (opcodes >> 1) & 1, opcodes & 1
@@ -50,7 +54,10 @@ def test_chunked_bit_kernels_match_sequential(kind):
                                   cur),
     }
     want_flat, want_obs = _sequential(flat, gword, bit, steps[kind])
-    args = (jnp.asarray(flat), jnp.asarray(gword), jnp.asarray(bit))
+    words = jnp.asarray(flat)
+    if lanes:
+        words = bitops.lane_view(words)
+    args = (words, jnp.asarray(gword), jnp.asarray(bit))
     if kind == "set":
         got_flat, got = bitops.scatter_set_bits(*args)
     elif kind == "clear":
@@ -64,7 +71,9 @@ def test_chunked_bit_kernels_match_sequential(kind):
         got_flat, got = bitops.scatter_bit_affine(
             *args, jnp.asarray(b_coef), jnp.asarray(a_coef))
     np.testing.assert_array_equal(np.asarray(got), want_obs)
-    np.testing.assert_array_equal(np.asarray(got_flat)[:-1], want_flat[:-1])
+    got_words = np.asarray(got_flat).reshape(-1)
+    np.testing.assert_array_equal(got_words[:WORDS], want_flat[:-1])
+    assert got_words.shape[0] == WORDS + (not lanes)
 
 
 def test_chunked_hll_add_changed_matches_sequential():

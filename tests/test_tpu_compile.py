@@ -192,6 +192,69 @@ def test_bloom_55m_contains_gather_memory(ex):
     assert c.memory_analysis().temp_size_in_bytes < 1 << 31
 
 
+_HBM = 16 * 10**9  # one v5e chip
+
+
+def _computation(hlo: str, name: str) -> list:
+    """Instruction lines of the HLO computation ``name``."""
+    lines = hlo.splitlines()
+    start = next(i for i, ln in enumerate(lines)
+                 if ln.startswith(f"%{name} ") or ln.startswith(f"{name} "))
+    end = next(i for i in range(start, len(lines)) if lines[i] == "}")
+    return lines[start + 1:end]
+
+
+def _elements(line: str) -> int:
+    """Element count of an instruction's array output, 0 for a tuple."""
+    out = line.split(" = ", 1)[1]
+    dims = out[out.index("[") + 1:out.index("]")] if out[0] != "(" else ""
+    return int(np.prod([int(d) for d in dims.split(",") if d])) if dims else 0
+
+
+def _scatters(hlo: str, line: str) -> bool:
+    """Whether an instruction is a scatter or a fusion that holds one."""
+    if " scatter(" in line:
+        return True
+    if "calls=%" not in line:
+        return False
+    name = line.split("calls=%", 1)[1].split(",", 1)[0].split("}", 1)[0]
+    return any(_scatters(hlo, ln) for ln in _computation(hlo, name))
+
+
+def test_bloom_55m_add_scan_carries_lanes(ex):
+    """The 55M-key fill's launch: 2^20 adds (k = 5) on the bloom-55m
+    pool (8 rows of 2^24 words, 512 MB) run the run-length scan, 1,024
+    steps of 1,024 ops.  It fits HBM, and the scan's while body makes
+    no pool-sized value but one fusion that holds a scatter: the
+    in-place 128-lane row scatter-add into the carried lane view.  The
+    per-step relayout of the pool (a slice and reshape of the whole
+    carry, 1.3 ms a step at HBM speed) cannot come back."""
+    m, B = 401_414_247, 1 << 20
+    pool = _pool(ex, PoolKind.BLOOM, (class_words_for_bits(m),))
+    assert class_words_for_bits(m) == 1 << 24
+    blocks = np.zeros((B, 4), np.uint32)
+    blocks[:, :2] = np.random.default_rng(2).integers(0, 1 << 32, (B, 2))
+    c = _compile(lambda: ex.bloom_mixed_keys_runs(
+        pool, 5, blocks, np.uint32(8), np.zeros(1, np.int32),
+        np.full(1, m, np.uint32), np.ones(1, bool),
+        np.array([0, B], np.int32)))
+    mem = c.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes) < _HBM
+    hlo = c.as_text()
+    (loop,) = [ln for ln in hlo.splitlines() if " while(" in ln]
+    body = _computation(hlo, loop.split("body=%", 1)[1].split(",", 1)[0])
+    words = pool.state.shape[0] - 1
+    sized = [ln.strip() for ln in body
+             if " = " in ln and _elements(ln) in (words, words + 1)
+             and not any(f" {op}(" in ln for op in
+                         ("parameter", "get-tuple-element", "bitcast"))]
+    assert len(sized) == 1, sized
+    (write,) = sized
+    assert " fusion(" in write and "u32[1048576,128]" in write, write
+    assert _scatters(hlo, write)
+
+
 @pytest.mark.parametrize("op", ["hll_add", "hll_add_changed"])
 def test_hll_p14(ex, op):
     """Config 2: p=14 registers, 1M-op launch."""
